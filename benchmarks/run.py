@@ -52,6 +52,9 @@ def main(argv=None) -> None:
     if smoke:
         os.environ["REPRO_BENCH_SMOKE"] = "1"
 
+    from repro.kernels import backend
+    backend.use_compile_cache()
+
     from . import (block_query, coordination, nodeprog, recovery,
                    replication, roofline, scalability, scaling, serving,
                    snapshot, social, traversal, writepath)

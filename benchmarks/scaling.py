@@ -315,6 +315,9 @@ print("RESULT " + json.dumps(out))
 def run_child(devices: int, n_users: int, deg: int) -> Dict:
     env = dict(os.environ)
     env.pop("XLA_FLAGS", None)
+    # forced host devices exist only on the CPU backend; pinning it keeps
+    # the children off an accelerator the parent may already hold
+    env["JAX_PLATFORMS"] = "cpu"
     env["PYTHONPATH"] = os.path.join(REPO_ROOT, "src") + (
         os.pathsep + env["PYTHONPATH"] if env.get("PYTHONPATH") else "")
     proc = subprocess.run(

@@ -17,7 +17,7 @@ maintains incrementally on its write path (packed ``(N, G+1)`` int32
 create/delete stamp matrices plus interned src/dst id columns):
 
 * **cold build** — concatenate shard columns, evaluate visibility with
-  ONE batched pass (`repro.kernels.mv_visibility` compiled on TPU/GPU,
+  ONE batched pass (`repro.kernels.mv_visibility` compiled on the TPU,
   `clock.visibility_mask_np` on CPU), refine the truly-concurrent stamps
   through a SINGLE timeline-oracle request, then compact the visible
   rows with vectorized numpy into CSR-sorted edge arrays;
@@ -56,6 +56,7 @@ import numpy as np
 import jax
 import jax.numpy as jnp
 
+from ..kernels import backend
 from . import clock
 from .clock import NO_STAMP, Order, Stamp, compare
 from .oracle import KIND_PROG, KIND_TX
@@ -135,17 +136,18 @@ class GraphArrays:
 
 
 # ---------------------------------------------------------------------------
-# Batched visibility primitives (kernel on TPU/GPU, numpy on CPU).
+# Batched visibility primitives (kernel on the TPU, numpy on the CPU).
 # ---------------------------------------------------------------------------
 
-#: test hook: force (True) / forbid (False) the Pallas kernel; None = auto
+#: test hook: force (True) / forbid (False) the Pallas kernel; None takes
+#: the choice of repro.kernels.backend
 FORCE_KERNEL: Optional[bool] = None
 
 
 def _use_kernel() -> bool:
     if FORCE_KERNEL is not None:
         return FORCE_KERNEL
-    return jax.default_backend() != "cpu"
+    return backend.on_device()
 
 
 def _before_batch(rows: np.ndarray, q: np.ndarray) -> np.ndarray:
@@ -154,11 +156,9 @@ def _before_batch(rows: np.ndarray, q: np.ndarray) -> np.ndarray:
         return np.zeros((0,), bool)
     if _use_kernel():
         from repro.kernels.mv_visibility import ops
-        # before(x) == visible(create=x, delete=absent)
-        no = np.full_like(rows, NO_STAMP)
-        return np.asarray(ops.visibility_mask(jnp.asarray(rows),
-                                              jnp.asarray(no),
-                                              jnp.asarray(q)))
+        n = rows.shape[0]
+        rows = backend.pad_pow2(rows, NO_STAMP, floor=ops.DEFAULT_BLOCK_N)
+        return np.asarray(ops.before_mask(rows, np.asarray(q)))[:n]
     return clock._np_before(rows, q)
 
 

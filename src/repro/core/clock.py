@@ -25,12 +25,8 @@ import enum
 from dataclasses import dataclass
 from typing import Optional, Sequence, Tuple
 
+import jax.numpy as jnp
 import numpy as np
-
-try:  # jnp ops are optional at import time (control-plane only users)
-    import jax.numpy as jnp
-except Exception:  # pragma: no cover
-    jnp = None
 
 
 class Order(enum.Enum):
@@ -154,25 +150,25 @@ def concurrent_mask_np(rows: np.ndarray, q: np.ndarray) -> np.ndarray:
     return (~is_no) & same_epoch & ((~le & ~ge) | eq)
 
 
-if jnp is not None:
+def _jnp_before(rows, q):
+    is_no = rows[:, 0] == NO_STAMP
+    lower_epoch = rows[:, 0] < q[0]
+    same_epoch = rows[:, 0] == q[0]
+    le = jnp.all(rows[:, 1:] <= q[1:], axis=1)
+    eq = jnp.all(rows[:, 1:] == q[1:], axis=1)
+    return jnp.where(is_no, False, lower_epoch | (same_epoch & le & ~eq))
 
-    def _jnp_before(rows, q):
-        is_no = rows[:, 0] == NO_STAMP
-        lower_epoch = rows[:, 0] < q[0]
-        same_epoch = rows[:, 0] == q[0]
-        le = jnp.all(rows[:, 1:] <= q[1:], axis=1)
-        eq = jnp.all(rows[:, 1:] == q[1:], axis=1)
-        return jnp.where(is_no, False, lower_epoch | (same_epoch & le & ~eq))
 
-    def visibility_mask(create_rows, delete_rows, q):
-        """jnp version of :func:`visibility_mask_np` (jit/vmap friendly)."""
-        return _jnp_before(create_rows, q) & ~_jnp_before(delete_rows, q)
+def visibility_mask(create_rows, delete_rows, q):
+    """jnp version of :func:`visibility_mask_np` (jit/vmap friendly)."""
+    return _jnp_before(create_rows, q) & ~_jnp_before(delete_rows, q)
 
-    def concurrent_mask(rows, q):
-        """rows[i] ≈ q (same epoch, vector-incomparable)."""
-        is_no = rows[:, 0] == NO_STAMP
-        same_epoch = rows[:, 0] == q[0]
-        le = jnp.all(rows[:, 1:] <= q[1:], axis=1)
-        ge = jnp.all(rows[:, 1:] >= q[1:], axis=1)
-        eq = le & ge
-        return (~is_no) & same_epoch & ~le & ~ge | ((~is_no) & same_epoch & eq)
+
+def concurrent_mask(rows, q):
+    """rows[i] ≈ q (same epoch, vector-incomparable)."""
+    is_no = rows[:, 0] == NO_STAMP
+    same_epoch = rows[:, 0] == q[0]
+    le = jnp.all(rows[:, 1:] <= q[1:], axis=1)
+    ge = jnp.all(rows[:, 1:] >= q[1:], axis=1)
+    eq = le & ge
+    return (~is_no) & same_epoch & ~le & ~ge | ((~is_no) & same_epoch & eq)

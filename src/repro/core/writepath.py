@@ -58,8 +58,11 @@ batched == per-tx equivalence.
 from __future__ import annotations
 
 from dataclasses import dataclass, field
+import functools
 from typing import Dict, List, Optional, Sequence, Tuple
 
+import jax
+import jax.numpy as jnp
 import numpy as np
 
 from .clock import NO_STAMP, Order, Stamp, compare, pack
@@ -124,7 +127,7 @@ def wal_replay_shard(wal: Sequence[WalRecord], shard: int
 def _before_pairs_xp(xp, rows, qs):
     """``rows[i] ≺ qs[i]`` elementwise over two (M, G+1) stamp matrices,
     written once over the array module (``xp`` = numpy or jax.numpy) so
-    the CPU and accelerator paths cannot drift.
+    the CPU and TPU paths cannot drift.
 
     The pairwise form of :func:`repro.core.clock._np_before` (there the
     query stamp is shared); absent rows (``NO_STAMP``) are never
@@ -143,18 +146,28 @@ def _np_before_pairs(rows: np.ndarray, qs: np.ndarray) -> np.ndarray:
 
 def before_pairs(rows: np.ndarray, qs: np.ndarray) -> np.ndarray:
     """Elementwise ``rows[i] ≺ qs[i]`` with the data plane's backend
-    auto-switch: numpy on CPU; on an accelerator backend the identical
-    elementwise compare runs as one fused jnp launch (the
+    choice (``analytics._use_kernel``): numpy on the CPU; on the TPU the
+    identical elementwise compare runs as one jitted launch (the
     ``mv_visibility`` kernel's contract generalized to a per-row query
     stamp, which the single-``q`` Pallas kernel cannot express)."""
     if rows.shape[0] == 0:
         return np.zeros((0,), bool)
     from . import analytics
     if analytics._use_kernel():
-        import jax.numpy as jnp
-        return np.asarray(_before_pairs_xp(jnp, jnp.asarray(rows),
-                                           jnp.asarray(qs)))
+        return _device_before_pairs(rows, qs)
     return _before_pairs_xp(np, rows, qs)
+
+
+_jit_before_pairs = jax.jit(functools.partial(_before_pairs_xp, jnp))
+
+
+def _device_before_pairs(rows: np.ndarray, qs: np.ndarray) -> np.ndarray:
+    """One launch per window, M padded with absent rows (never before)
+    to a power-of-two bucket."""
+    from ..kernels.backend import pad_pow2
+    m = rows.shape[0]
+    return np.asarray(_jit_before_pairs(pad_pow2(rows, NO_STAMP),
+                                        pad_pow2(qs, 0)))[:m]
 
 
 # ---------------------------------------------------------------------------

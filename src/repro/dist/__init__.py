@@ -54,17 +54,3 @@ def constrain(x, spec_fn: Callable):
     return jax.lax.with_sharding_constraint(
         x, NamedSharding(mesh, spec_fn(mesh)))
 
-
-def shard_map(f, **kw):
-    """Version-portable shard_map: the top-level ``jax.shard_map`` alias
-    (and its ``check_vma`` kwarg) landed after 0.4.x; fall back to
-    ``jax.experimental.shard_map`` with ``check_rep`` there."""
-    import jax
-
-    if hasattr(jax, "shard_map"):
-        return jax.shard_map(f, **kw)
-    from jax.experimental.shard_map import shard_map as _sm
-
-    if "check_vma" in kw:
-        kw["check_rep"] = kw.pop("check_vma")
-    return _sm(f, **kw)

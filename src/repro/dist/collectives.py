@@ -115,7 +115,6 @@ def moe_alltoall_block(xf, logits, w_gate, w_up, w_down, mesh, top_k,
 
     from jax.sharding import PartitionSpec as P
 
-    from . import shard_map
     from .sharding import dp_axes
 
     n_model = int(mesh.shape["model"])
@@ -205,9 +204,9 @@ def moe_alltoall_block(xf, logits, w_gate, w_up, w_down, mesh, top_k,
 
     spec_tok = P(tok_axes, None)
     spec_w = P("model", None, None)
-    return shard_map(body, mesh=mesh,
-                     in_specs=(spec_tok, spec_tok, spec_w, spec_w, spec_w),
-                     out_specs=spec_tok, check_vma=False)(
+    return jax.shard_map(body, mesh=mesh,
+                         in_specs=(spec_tok, spec_tok, spec_w, spec_w, spec_w),
+                         out_specs=spec_tok, check_vma=False)(
         xf, logits, w_gate, w_up, w_down)
 
 
@@ -235,7 +234,6 @@ def moe_alltoall_exact_c_dev(logits, mesh, top_k, capacity=None,
 
     from jax.sharding import PartitionSpec as P
 
-    from . import shard_map
     from .sharding import dp_axes
 
     if isinstance(logits, jax.core.Tracer):
@@ -274,8 +272,8 @@ def moe_alltoall_exact_c_dev(logits, mesh, top_k, capacity=None,
         return kept[None, :]                 # (1, n_model) per device
 
     spec_tok = P(tok_axes, None)
-    counts = shard_map(count, mesh=mesh, in_specs=(spec_tok,),
-                       out_specs=spec_tok, check_vma=False)(logits)
+    counts = jax.shard_map(count, mesh=mesh, in_specs=(spec_tok,),
+                           out_specs=spec_tok, check_vma=False)(logits)
     max_kept = int(jax.device_get(counts).max())
     return min(bound, max(8, -(-max_kept // 8) * 8))
 
@@ -305,7 +303,6 @@ def grad_sync(mesh, grads, int8_cross_pod: bool = False):
         return grads
     from jax.sharding import PartitionSpec as P
 
-    from . import shard_map
     from .sharding import dp_axes
 
     dp = dp_axes(mesh)
@@ -324,7 +321,8 @@ def grad_sync(mesh, grads, int8_cross_pod: bool = False):
 
         return jax.tree_util.tree_map(one, g)
 
-    return shard_map(body, mesh=mesh, in_specs=(P(),), out_specs=P())(grads)
+    return jax.shard_map(body, mesh=mesh, in_specs=(P(),),
+                         out_specs=P())(grads)
 
 
 def cross_pod_allreduce(mesh, x, compress: bool = False, in_spec=None):
@@ -342,12 +340,11 @@ def cross_pod_allreduce(mesh, x, compress: bool = False, in_spec=None):
         return x
     from jax.sharding import PartitionSpec as P
 
-    from . import shard_map
 
     spec = in_spec if in_spec is not None else P()
 
     def body(xl):
         return _pod_mean(xl.astype(jnp.float32), compress).astype(x.dtype)
 
-    return shard_map(body, mesh=mesh, in_specs=(spec,), out_specs=spec,
-                     check_vma=False)(x)
+    return jax.shard_map(body, mesh=mesh, in_specs=(spec,), out_specs=spec,
+                         check_vma=False)(x)

@@ -32,9 +32,10 @@ plane"):
   XLA specializations); a compaction remap re-uploads that shard's
   block (same O(live) cost class as the host engines' remap).
 
-CPU vs accelerator: the block kernel is ``clock._jnp_before`` (pure
-int32 jnp, bit-identical to ``_np_before``) on CPU and the Pallas
-``before`` kernel (``kernels.mv_visibility``) off-CPU.  The host-global
+CPU vs TPU (``repro.kernels.backend``): the block kernel is
+``clock._jnp_before`` (pure int32 jnp, bit-identical to ``_np_before``)
+on the CPU and the compiled Pallas ``before`` kernel
+(``kernels.mv_visibility``) on the TPU.  The host-global
 path stays the equivalence oracle — see ``WeaverConfig.device_shard_columns``.
 """
 
@@ -50,6 +51,31 @@ _MIN_BLOCK = 64
 
 def _pow2(n: int) -> int:
     return 1 << max(0, int(n - 1)).bit_length() if n > 1 else 1
+
+
+def visibility_launch(mesh, use_pallas: bool):
+    """The jitted ``shard_map`` answering ``row ≺ q`` for (S, 4, B, C)
+    stamp blocks sharded over ``mesh``'s ``"data"`` axis: the compiled
+    Pallas ``before`` kernel when ``use_pallas``, else
+    ``clock._jnp_before``.  Returns (S, 4, B) bool."""
+    import jax
+    from jax.sharding import PartitionSpec as P
+
+    from ..core import clock
+    from ..kernels.mv_visibility import ops
+
+    def block_fn(blk, q):
+        # blk (spd, 4, B, C) — this device's blocks; q (C,) replicated
+        rows = blk.reshape(-1, blk.shape[-1])
+        if use_pallas:
+            m = ops.before_mask(rows, q, interpret=False)
+        else:
+            m = clock._jnp_before(rows, q)
+        return m.reshape(blk.shape[:-1])
+
+    return jax.jit(jax.shard_map(block_fn, mesh=mesh,
+                                 in_specs=(P("data"), P()),
+                                 out_specs=P("data"), check_vma=False))
 
 
 class DeviceColumnPlane:
@@ -94,21 +120,18 @@ class DeviceColumnPlane:
         (or an unseen partition / capacity overflow) re-uploads or
         rebuilds."""
         live = [c for c in shard_cols if c is not None]
-        need = self._cap
         fresh = not self._dev
         for cols in live:
             bi = self._idx.get(id(cols))
             if bi is None or self._cols[bi] is not cols:
                 fresh = True
-            need = max(need, cols.n_v, cols.n_e)
-        if fresh or need > self._cap:
-            for cols in live:
-                bi = self._idx.get(id(cols))
-                if bi is None or self._cols[bi] is not cols:
-                    self._idx[id(cols)] = len(self._cols)
-                    self._cols.append(cols)
-                    self._consumed.append(None)
-            self._rebuild(need)
+                self._idx[id(cols)] = len(self._cols)
+                self._cols.append(cols)
+                self._consumed.append(None)
+        if fresh or any(max(c.n_v, c.n_e) > self._cap for c in live):
+            # size from the partitions alone: a floor at the old
+            # capacity would double it on every rebuild
+            self._rebuild(max(max(c.n_v, c.n_e) for c in self._cols))
             return
         for cols in live:
             self._sync_one(self._idx[id(cols)], cols)
@@ -207,31 +230,9 @@ class DeviceColumnPlane:
     # ---- the sharded launch ----------------------------------------------
 
     def _get_launch(self):
-        if self._launch is not None:
-            return self._launch
-        import jax
-        import jax.numpy as jnp
-        from jax.sharding import PartitionSpec as P
-
-        from .. import dist
-        from ..core import clock
-
-        use_pallas = jax.default_backend() != "cpu"
-
-        def block_fn(blk, q):
-            # blk (spd, 4, B, C) — this device's blocks; q (C,) replicated
-            rows = blk.reshape(-1, blk.shape[-1])
-            if use_pallas:
-                from ..kernels.mv_visibility import ops
-                m = ops.before_mask(rows, q)
-            else:
-                m = clock._jnp_before(rows, q)
-            return m.reshape(blk.shape[:-1])
-
-        f = dist.shard_map(block_fn, mesh=self.mesh,
-                           in_specs=(P("data"), P()), out_specs=P("data"),
-                           check_vma=False)
-        self._launch = jax.jit(f)
+        if self._launch is None:
+            from ..kernels import backend
+            self._launch = visibility_launch(self.mesh, backend.on_device())
         return self._launch
 
     def before_all(self, q: np.ndarray) -> None:

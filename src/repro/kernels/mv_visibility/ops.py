@@ -2,23 +2,35 @@
 ``repro.core.clock.pack_many``, pads N to the block size, and dispatches
 to the Pallas kernel.
 
-Backend selection: ``interpret=None`` (the default) auto-detects —
-compiled Pallas on TPU/GPU, interpreter mode on CPU (where no Mosaic
-backend exists).  Pass an explicit bool to override (tests force
-``interpret=True`` to exercise the kernel body on CPU).
+Backend selection: ``interpret=None`` (the default) takes the choice of
+:mod:`repro.kernels.backend` — compiled Pallas on the TPU, interpreter
+mode on the CPU (where no Mosaic backend exists).  Pass an explicit bool
+to override (tests force ``interpret=True`` to exercise the kernel body
+on CPU).  :data:`CALLS` counts host dispatches by the mode they ran in.
 """
 
 from __future__ import annotations
 
+from collections import Counter
 from typing import Optional
 
-import jax
 import jax.numpy as jnp
-import numpy as np
 
+from .. import backend
 from .kernel import DEFAULT_BLOCK_N, NO_STAMP, before_pallas, \
-    default_interpret, visibility_pallas
+    visibility_pallas
 from .ref import before_cm, visibility_ref
+
+#: host dispatches by mode: "compiled", "interpreted" or "ref"
+CALLS: Counter = Counter()
+
+
+def _mode(interpret: Optional[bool], use_ref: bool) -> bool:
+    """Resolve ``interpret`` and count the dispatch."""
+    interpret = backend.interpret(interpret)
+    CALLS["ref" if use_ref else
+          "interpreted" if interpret else "compiled"] += 1
+    return interpret
 
 
 def visibility_mask(create_rows: jnp.ndarray, delete_rows: jnp.ndarray,
@@ -26,8 +38,7 @@ def visibility_mask(create_rows: jnp.ndarray, delete_rows: jnp.ndarray,
                     interpret: Optional[bool] = None,
                     use_ref: bool = False) -> jnp.ndarray:
     """(N, C) stamp rows + (C,) query -> (N,) bool visibility mask."""
-    if interpret is None:
-        interpret = default_interpret()
+    interpret = _mode(interpret, use_ref)
     n, c = create_rows.shape
     create_cm = jnp.asarray(create_rows).T
     delete_cm = jnp.asarray(delete_rows).T
@@ -51,9 +62,9 @@ def before_mask(rows: jnp.ndarray, q: jnp.ndarray,
                 interpret: Optional[bool] = None,
                 use_ref: bool = False) -> jnp.ndarray:
     """(N, C) stamp rows + (C,) query -> (N,) bool ``row ≺ q`` mask
-    (the single-table half of :func:`visibility_mask`)."""
-    if interpret is None:
-        interpret = default_interpret()
+    (the single-table half of :func:`visibility_mask`).  Traced inside
+    an outer ``jit`` it counts once per trace, not per launch."""
+    interpret = _mode(interpret, use_ref)
     n, c = rows.shape
     rows_cm = jnp.asarray(rows).T
     q = jnp.asarray(q)

@@ -20,12 +20,15 @@ Padding edges carry ``dst = -1`` and never match a one-hot row.
 from __future__ import annotations
 
 import functools
+from typing import Optional
 
 import jax
 import jax.numpy as jnp
 import numpy as np
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
+
+from .. import backend
 
 DEFAULT_BLOCK_N = 128     # destination nodes per block
 DEFAULT_BLOCK_E = 256     # edges per tile
@@ -64,10 +67,11 @@ def segment_mp_pallas(xs_packed: jnp.ndarray, dst_packed: jnp.ndarray,
                       w: jnp.ndarray, n_nodes: int,
                       block_n: int = DEFAULT_BLOCK_N,
                       block_e: int = DEFAULT_BLOCK_E,
-                      interpret: bool = True) -> jnp.ndarray:
+                      interpret: Optional[bool] = None) -> jnp.ndarray:
     """xs_packed (n_blocks*max_tiles*BE, D) gathered + padded features,
     dst_packed (n_blocks*max_tiles*BE,) global dst ids (-1 pad),
     w (D, F) -> y (n_nodes_padded, F) with n_nodes_padded = n_blocks*BN.
+    ``interpret=None`` takes the choice of :mod:`repro.kernels.backend`.
     """
     d = xs_packed.shape[1]
     f = w.shape[1]
@@ -90,6 +94,6 @@ def segment_mp_pallas(xs_packed: jnp.ndarray, dst_packed: jnp.ndarray,
         out_specs=pl.BlockSpec((block_n, f), lambda i, j: (i, 0)),
         out_shape=jax.ShapeDtypeStruct((n_nodes, f), w.dtype),
         scratch_shapes=[pltpu.VMEM((block_n, f), jnp.float32)],
-        interpret=interpret,
+        interpret=backend.interpret(interpret),
     )(xs_packed, dst_packed[None, :], w)
     return out

@@ -302,7 +302,7 @@ def _rec_serve(spec, dims, mesh, variant):
             off = jax.lax.axis_index("model") * sc.shape[-1]
             return v_loc, (i_loc + off).astype(jnp.int32)
 
-        v_loc, i_loc = _dist.shard_map(
+        v_loc, i_loc = jax.shard_map(
             local_topk, mesh=mesh,
             in_specs=_P(dp, "model"),
             out_specs=(_P(dp, "model"), _P(dp, "model")))(scores)

@@ -492,7 +492,7 @@ def _decode_attn_splitk(p, x, cfg: LMConfig, k_cache, v_cache, lens,
 
     cache_spec = P(bax, "model", None, None)
     small_spec = P(bax, None, None, None)
-    o, nk, nv = dist.shard_map(
+    o, nk, nv = jax.shard_map(
         local, mesh=mesh,
         in_specs=(small_spec, small_spec, small_spec, cache_spec,
                   cache_spec, P(bax)),

@@ -216,3 +216,24 @@ class TestShardedEqualsHostGlobal:
             if flag:
                 assert w.device_plane.stats["launches"] > 0
         assert results[True] == results[False]
+
+    def test_capacity_tracks_largest_partition(self):
+        """Shards joining the plane one at a time (one ShardPlan build
+        each) rebuild it at the size the largest partition needs, not
+        at a multiple of the previous capacity."""
+        from repro.core import Weaver, WeaverConfig
+        from repro.dist.columns import _pow2
+
+        w = Weaver(WeaverConfig(n_gatekeepers=2, n_shards=4, seed=3,
+                                device_shard_columns=True))
+        tx = w.begin_tx()
+        for i in range(400):
+            tx.create_vertex(f"n{i}")
+        assert w.run_tx(tx).ok
+        plane = w.device_plane
+        cols = [sh.partition.columns for sh in w.shards]
+        for c in cols:
+            plane.sync([c])
+        assert plane.stats["rebuilds"] == len(cols)
+        need = max(max(c.n_v, c.n_e) for c in cols)
+        assert plane._cap == _pow2(max(plane.min_block, need + need // 4))

@@ -379,12 +379,14 @@ class TestCompactionDelta:
 
 
 class TestSortedSegmentOps:
+    # 100 rows pad to a 128 bucket; 128 and 1 need no padding
+    @pytest.mark.parametrize("n", [1, 100, 128])
     @pytest.mark.parametrize("op", ["min", "max", "sum"])
-    def test_matches_dense_reference(self, op):
+    def test_matches_dense_reference(self, op, n):
         from repro.kernels.segment_mp import ops as smp
         rng = np.random.default_rng(3)
-        keys = np.sort(rng.integers(0, 20, 100))
-        vals = rng.normal(size=100)
+        keys = np.sort(rng.integers(0, 20, n))
+        vals = rng.normal(size=n)
         uniq, red = smp.segment_reduce_sorted(vals, keys, op, use_jax=False)
         uj, rj = smp.segment_reduce_sorted(vals, keys, op, use_jax=True)
         np.testing.assert_array_equal(uniq, uj)

@@ -62,8 +62,6 @@ class TestLoopAwareCosting:
             pytest.skip("needs >1 device (run under forced host devices)")
         from jax.sharding import PartitionSpec as P
 
-        from repro import dist
-
         ndev = len(jax.devices())
         mesh = jax.make_mesh((ndev,), ("d",))
         d, n = 64, 8
@@ -75,8 +73,8 @@ class TestLoopAwareCosting:
                     return c + jax.lax.psum(c, "d"), None
                 y, _ = jax.lax.scan(body, xs, None, length=n)
                 return y
-            return dist.shard_map(inner, mesh=mesh, in_specs=P("d"),
-                                  out_specs=P("d"))(x)
+            return jax.shard_map(inner, mesh=mesh, in_specs=P("d"),
+                                 out_specs=P("d"))(x)
 
         def unrolled(x):
             def inner(xs):
@@ -84,8 +82,8 @@ class TestLoopAwareCosting:
                 for _ in range(n):
                     c = c + jax.lax.psum(c, "d")
                 return c
-            return dist.shard_map(inner, mesh=mesh, in_specs=P("d"),
-                                  out_specs=P("d"))(x)
+            return jax.shard_map(inner, mesh=mesh, in_specs=P("d"),
+                                 out_specs=P("d"))(x)
 
         r_scan = hlo_cost.analyze(_compile(scanned, x).as_text())
         r_unroll = hlo_cost.analyze(_compile(unrolled, x).as_text())

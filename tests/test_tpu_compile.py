@@ -1,0 +1,107 @@
+"""Compile the data plane's Pallas kernels for a described TPU v5e.
+
+Nothing here runs on a chip: the TPU compiler lowers each kernel for a
+``v5e:2x2`` topology that is described, not attached, so Mosaic rejects
+here what it would reject on the chip (unsupported vector types,
+misaligned tiles, too much VMEM).  This is the only file that describes
+the chip; the description happens inside a fixture, never at import
+time, so every pytest-xdist worker collects the same tests and only the
+worker running this file loads the TPU library.
+"""
+import os
+
+import numpy as np
+import pytest
+import jax
+import jax.numpy as jnp
+from jax.sharding import Mesh, NamedSharding, PartitionSpec as P, \
+    SingleDeviceSharding
+
+from repro.dist.columns import TABLES, visibility_launch
+from repro.kernels.mv_visibility.kernel import before_pallas, \
+    visibility_pallas
+from repro.kernels.segment_mp.kernel import DEFAULT_BLOCK_E, \
+    DEFAULT_BLOCK_N, segment_mp_pallas
+
+#: rows per (table, shard) block of the smoke deployment: 1M edges over
+#: 8 shards rounds up to 2^18, and 2^20 is the per-launch row count
+SMOKE_BLOCK = 1 << 18
+
+
+@pytest.fixture(scope="module")
+def topo():
+    from jax.experimental import topologies
+    from jax.experimental.compilation_cache import compilation_cache
+
+    # compiles for a described chip are written to a persistent cache
+    # but cannot be read back without one: keep them out of it
+    was = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    compilation_cache.reset_cache()
+    try:
+        with pytest.MonkeyPatch.context() as mp:
+            mp.setenv("TPU_LOG_DIR",
+                      os.environ.get("TPU_LOG_DIR", "disabled"))
+            try:
+                desc = topologies.get_topology_desc(
+                    platform="tpu", topology_name="v5e:2x2")
+            except Exception as e:
+                pytest.skip(f"no v5e:2x2 topology can be described "
+                            f"here: {e}")
+            yield desc
+    finally:
+        jax.config.update("jax_enable_compilation_cache", was)
+        compilation_cache.reset_cache()
+
+
+@pytest.fixture(scope="module")
+def one_chip(topo):
+    return SingleDeviceSharding(topo.devices[0])
+
+
+@pytest.mark.parametrize("n", [1024, 1 << 20])
+@pytest.mark.parametrize("c", [2, 5, 9])
+@pytest.mark.parametrize("kernel", ["visibility", "before"])
+def test_mv_visibility_compiles(one_chip, kernel, c, n):
+    rows = jax.ShapeDtypeStruct((c, n), jnp.int32, sharding=one_chip)
+    q = jax.ShapeDtypeStruct((c,), jnp.int32, sharding=one_chip)
+    if kernel == "visibility":
+        fn = lambda cr, de, q: visibility_pallas(cr, de, q, interpret=False)
+        args = (rows, rows, q)
+    else:
+        fn = lambda r, q: before_pallas(r, q, interpret=False)
+        args = (rows, q)
+    compiled = jax.jit(fn).lower(*args).compile()
+    assert "tpu_custom_call" in compiled.as_text()
+
+
+@pytest.mark.parametrize("d,f", [(128, 128), (64, 64)])
+def test_segment_mp_compiles(one_chip, d, f):
+    n_nodes, tiles = 8 * DEFAULT_BLOCK_N, 4
+    n_edges = (n_nodes // DEFAULT_BLOCK_N) * tiles * DEFAULT_BLOCK_E
+    xs = jax.ShapeDtypeStruct((n_edges, d), jnp.float32, sharding=one_chip)
+    dst = jax.ShapeDtypeStruct((n_edges,), jnp.int32, sharding=one_chip)
+    w = jax.ShapeDtypeStruct((d, f), jnp.float32, sharding=one_chip)
+    fn = lambda xs, dst, w: segment_mp_pallas(xs, dst, w, n_nodes,
+                                              interpret=False)
+    compiled = jax.jit(fn).lower(xs, dst, w).compile()
+    assert "tpu_custom_call" in compiled.as_text()
+
+
+def test_column_plane_launch_compiles_on_four_chips(topo):
+    """The device plane's one sharded launch with the Pallas branch, at
+    the smoke deployment's resident size (8 shards, 4 gatekeepers)."""
+    mesh = Mesh(np.array(topo.devices[:4]), ("data",))
+    n_shards, c = 8, 5
+    blocks = jax.ShapeDtypeStruct((n_shards, TABLES, SMOKE_BLOCK, c),
+                                  jnp.int32,
+                                  sharding=NamedSharding(mesh, P("data")))
+    q = jax.ShapeDtypeStruct((c,), jnp.int32,
+                             sharding=NamedSharding(mesh, P()))
+    compiled = visibility_launch(mesh, use_pallas=True).lower(
+        blocks, q).compile()
+    assert "tpu_custom_call" in compiled.as_text()
+    # each chip holds a quarter of the blocks (plus the replicated q)
+    per_chip = n_shards * TABLES * SMOKE_BLOCK * c * 4 // 4
+    assert per_chip <= compiled.memory_analysis().argument_size_in_bytes \
+        < per_chip + 1024

@@ -157,10 +157,13 @@ class TestLastUpdateTable:
             elif op["op"] == "delete_vertex":
                 live.discard(op["vid"])
 
-    def test_classifier_matches_compare(self):
+    @pytest.mark.parametrize("force_kernel", [None, True])
+    def test_classifier_matches_compare(self, force_kernel, monkeypatch):
         """classify_write_sets must reproduce clock.compare semantics
         per (tx, vid) row: AFTER -> retry, CONCURRENT -> refine residue,
-        BEFORE/absent -> pass."""
+        BEFORE/absent -> pass — on the host path and on the device
+        path's padded launch alike."""
+        monkeypatch.setattr(A, "FORCE_KERNEL", force_kernel)
         rng = np.random.default_rng(3)
         table = LastUpdateTable()
         stamps = {}
