@@ -48,7 +48,7 @@ N_USERS = 100_000
 AVG_DEGREE = 10
 TX_OPS = 1024            # operations per load transaction
 WRITE_WINDOW = 0.5e-3    # group-commit window, simulated seconds
-N_MIX = 400              # TAO-mix requests
+N_MIX = 4000             # TAO-mix requests: ~8 writes at read_frac 0.998
 BATCH = 4                # requests per heavy-program batch
 N_WRITES = 64            # transactions in the write burst
 
@@ -131,6 +131,7 @@ def serve(w, vertices, rng, chk: ReadChecker) -> dict:
 
     srv = GraphQueryServer(w)
     acks = {"ok": 0, "aborted": 0}
+    kinds = {"create_edge": 0, "delete_edge": 0}
 
     def on_tx(r) -> None:
         acks["ok" if r.ok else "aborted"] += 1
@@ -142,6 +143,7 @@ def serve(w, vertices, rng, chk: ReadChecker) -> dict:
             entries = [(v, None)]
             srv.submit("prog", (kind, entries), chk.cb(kind, entries))
             continue
+        kinds[kind] += 1
         tx = w.begin_tx()
         if kind == "create_edge":
             tx.create_edge(v, op["u"])
@@ -153,6 +155,8 @@ def serve(w, vertices, rng, chk: ReadChecker) -> dict:
                 tx.set_vertex_prop(v, "touch", 1)
         srv.submit("tx", tx, on_tx)
     drain(srv, "TAO mix")
+    if not acks["ok"]:
+        raise RuntimeError(f"the TAO mix acknowledged no write ({kinds})")
 
     # one batch of each multi-hop / ragged program
     def pick():
@@ -195,7 +199,8 @@ def serve(w, vertices, rng, chk: ReadChecker) -> dict:
     if lost:
         raise RuntimeError(f"acknowledged writes not read back: {lost[:3]}")
     c = w.counters()
-    return {"mix_tx_ok": acks["ok"], "mix_tx_aborted": acks["aborted"],
+    return {"mix_writes": kinds, "mix_tx_ok": acks["ok"],
+            "mix_tx_aborted": acks["aborted"],
             "burst_writes": len(written), "tx_batches": c["tx_batches"],
             "conflict_rows_checked": c["conflict_rows_checked"]}
 

@@ -45,12 +45,10 @@ from typing import Dict, List, Optional, Sequence, Tuple
 
 import numpy as np
 
+from ..kernels import backend
+
 TABLES = 4            # v_create, v_delete, e_create, e_delete
 _MIN_BLOCK = 64
-
-
-def _pow2(n: int) -> int:
-    return 1 << max(0, int(n - 1)).bit_length() if n > 1 else 1
 
 
 def visibility_launch(mesh, use_pallas: bool):
@@ -141,7 +139,8 @@ class DeviceColumnPlane:
 
         s = len(self._cols)
         self._spd = max(1, -(-s // self.n_dev))
-        self._cap = _pow2(max(self.min_block, need_rows + need_rows // 4))
+        self._cap = backend.pow2(max(self.min_block,
+                                     need_rows + need_rows // 4))
         self._dev = []
         for d in range(self.n_dev):
             host = np.full((self._spd, TABLES, self._cap, self.c),
@@ -205,15 +204,12 @@ class DeviceColumnPlane:
         d, j = divmod(bi, self._spd)
         arr = self._dev[d]
         for t, idx, rows in ups:
-            m = idx.size
-            mp = _pow2(m)
-            if mp != m:          # pad to a pow2 bucket (dup-scatter safe)
-                idx = np.concatenate([idx, np.full(mp - m, idx[-1],
-                                                   np.int64)])
-                rows = np.concatenate(
-                    [rows, np.repeat(rows[-1:], mp - m, axis=0)])
-            arr = arr.at[j, t, jnp.asarray(idx), :].set(jnp.asarray(rows))
-            self.stats["row_updates"] += m
+            # pad to a pow2 bucket by repeating the last row (a duplicate
+            # scatter of equal values is safe)
+            idx_p = backend.pad_pow2(idx, idx[-1])
+            rows_p = backend.pad_pow2(rows, rows[-1])
+            arr = arr.at[j, t, jnp.asarray(idx_p), :].set(jnp.asarray(rows_p))
+            self.stats["row_updates"] += idx.size
         self._dev[d] = arr
 
     def _upload_block(self, bi: int, cols) -> None:
@@ -231,7 +227,6 @@ class DeviceColumnPlane:
 
     def _get_launch(self):
         if self._launch is None:
-            from ..kernels import backend
             self._launch = visibility_launch(self.mesh, backend.on_device())
         return self._launch
 

@@ -10,7 +10,8 @@ Two platforms are supported:
 Any other platform raises: a GPU would otherwise be handed a TPU Mosaic
 kernel and fail without saying why.
 
-:func:`pad_pow2` buckets the row counts of device launches, and
+:func:`pow2` is the one bucketing rule for the row counts of device
+launches and resident blocks (:func:`pad_pow2` applies it), and
 :func:`use_compile_cache` places JAX's persistent compilation cache for
 the entry points (``chip_smoke.py``, ``benchmarks/run.py``).
 """
@@ -55,12 +56,18 @@ def interpret(requested: Optional[bool] = None) -> bool:
     return platform() == "cpu"
 
 
+def pow2(n: int) -> int:
+    """The smallest power of two at or above ``n`` (1 for ``n <= 1``)."""
+    return 1 << max(0, n - 1).bit_length()
+
+
 def pad_pow2(a: np.ndarray, fill, floor: int = 1) -> np.ndarray:
-    """Pad ``a``'s rows with ``fill`` up to a power of two (at least
-    ``floor``), so device launches over batches of every size compile
-    one program per bucket instead of one per size."""
+    """Pad ``a``'s rows with ``fill`` (a scalar, or one row) up to
+    :func:`pow2` of its length (at least ``floor``), so device launches
+    over batches of every size compile one program per bucket instead
+    of one per size."""
     m = a.shape[0]
-    pad = max(floor, 1 << (m - 1).bit_length()) - m
+    pad = max(floor, pow2(m)) - m
     if not pad:
         return a
     return np.concatenate([a, np.full((pad,) + a.shape[1:], fill, a.dtype)])

@@ -10,21 +10,19 @@ frontier-batched node-program runtime (``repro.core.frontier``) for
 per-hop neighbour aggregation: it reduces values over *pre-sorted*
 segment keys, returning the compressed ``(unique_keys, reduced)`` form a
 frontier exchange wants (the next hop's packed frontier IS the unique
-key set).  On the CPU it is a ``reduceat`` over the sorted runs; on the
-TPU it routes through ``jax.ops.segment_*`` with ``indices_are_sorted=True`` —
-the same sortedness contract the block-ELL kernel exploits.
+key set).  It is a numpy ``reduceat`` over the sorted runs on every
+platform: its inputs and outputs live on the host, and ``sssp`` reduces
+float64 distances that a device reduction (x64 off) would round to
+float32.
 """
 
 from __future__ import annotations
 
-import functools
 from typing import Optional, Tuple
 
-import jax
 import jax.numpy as jnp
 import numpy as np
 
-from .. import backend
 from .kernel import (DEFAULT_BLOCK_E, DEFAULT_BLOCK_N, segment_mp_pallas)
 from .ref import segment_matmul_reduce_ref
 
@@ -60,11 +58,7 @@ def pack_edges(edge_src: np.ndarray, edge_dst: np.ndarray, n_nodes: int,
     return src_packed, dst_packed, n_pad
 
 
-_REDUCERS = {
-    "min": (np.minimum, "segment_min"),
-    "max": (np.maximum, "segment_max"),
-    "sum": (np.add, "segment_sum"),
-}
+_REDUCERS = {"min": np.minimum, "max": np.maximum, "sum": np.add}
 
 
 def segment_starts(keys: np.ndarray) -> np.ndarray:
@@ -76,44 +70,20 @@ def segment_starts(keys: np.ndarray) -> np.ndarray:
 
 
 def segment_reduce_sorted(values: np.ndarray, keys: np.ndarray,
-                          op: str = "min",
-                          use_jax: Optional[bool] = None):
+                          op: str = "min"):
     """Reduce ``values`` over equal runs of the SORTED ``keys``.
 
     Returns ``(unique_keys, reduced)`` — compressed form, one entry per
-    distinct key in ascending order.  ``use_jax=None`` takes the choice
-    of :mod:`repro.kernels.backend`: the jax segment op
-    (``indices_are_sorted=True``) on the TPU and the numpy
-    ``ufunc.reduceat`` fast path on the CPU; pass True/False to force.
+    distinct key in ascending order, reduced exactly in ``values``'
+    dtype.
     """
-    ufunc, seg_name = _REDUCERS[op]
+    ufunc = _REDUCERS[op]
     values = np.asarray(values)
     keys = np.asarray(keys)
     if keys.size == 0:
         return keys, values[:0]
-    if use_jax is None:
-        use_jax = backend.on_device()
     starts = segment_starts(keys)
-    uniq = keys[starts]
-    if not use_jax:
-        return uniq, ufunc.reduceat(values, starts)
-    # dense segment ids from the run starts, then the sorted segment op;
-    # rows and segments are padded to power-of-two buckets (pad rows go
-    # to one extra segment past the real ones) so a stream of frontiers
-    # compiles a few programs, not one per size
-    seg_ids = np.cumsum(np.r_[False, keys[1:] != keys[:-1]])
-    n_seg = 1 << int(uniq.size).bit_length()
-    out = _segment_reduce(jnp.asarray(backend.pad_pow2(values, values[0])),
-                          jnp.asarray(backend.pad_pow2(seg_ids, n_seg - 1)),
-                          seg_name, n_seg)
-    return uniq, np.asarray(out)[:uniq.size]
-
-
-@functools.partial(jax.jit, static_argnames=("seg_name", "num_segments"))
-def _segment_reduce(values, seg_ids, seg_name: str, num_segments: int):
-    return getattr(jax.ops, seg_name)(values, seg_ids,
-                                      num_segments=num_segments,
-                                      indices_are_sorted=True)
+    return keys[starts], ufunc.reduceat(values, starts)
 
 
 def segment_count_sorted(keys: np.ndarray):

@@ -222,7 +222,7 @@ class TestShardedEqualsHostGlobal:
         each) rebuild it at the size the largest partition needs, not
         at a multiple of the previous capacity."""
         from repro.core import Weaver, WeaverConfig
-        from repro.dist.columns import _pow2
+        from repro.kernels.backend import pow2
 
         w = Weaver(WeaverConfig(n_gatekeepers=2, n_shards=4, seed=3,
                                 device_shard_columns=True))
@@ -236,4 +236,4 @@ class TestShardedEqualsHostGlobal:
             plane.sync([c])
         assert plane.stats["rebuilds"] == len(cols)
         need = max(max(c.n_v, c.n_e) for c in cols)
-        assert plane._cap == _pow2(max(plane.min_block, need + need // 4))
+        assert plane._cap == pow2(max(plane.min_block, need + need // 4))
