@@ -326,6 +326,13 @@ class SnapshotEngine:
         """ONE oracle pass for every concurrent stamp of this snapshot."""
         if not pend:
             return
+        h = self.weaver.sim.host
+        if h is None:
+            self._order_pending(pend, at)
+        else:
+            h.call("oracle_refine", self._order_pending, pend, at)
+
+    def _order_pending(self, pend: List[tuple], at: Stamp) -> None:
         uniq: Dict[tuple, Stamp] = {}
         for _, _, s in pend:
             uniq[s.key()] = s
@@ -475,8 +482,23 @@ class SnapshotEngine:
             v_off += nv
             e_off += ne
 
-        # edge compaction + CSR/CSC sort (the int64 keys ARE the edge
-        # lists: src/dst indices live in the two 32-bit halves)
+        h = self.weaver.sim.host
+        if h is None:
+            self._compact_edges()
+        else:
+            h.call("csr_compact", self._compact_edges)
+
+        self.at = at
+        self.refine = refine
+        self._valid = True
+        self._vids_copy = None    # a rebuild may change vids at same len
+        self._vids_ver = 0        # bumped on every vids mutation
+        self.stats["cold"] += 1
+        self._make_ga()
+
+    def _compact_edges(self) -> None:
+        """Edge compaction + CSR/CSC sort (the int64 keys ARE the edge
+        lists: src/dst indices live in the two 32-bit halves)."""
         f0 = (self.e_vis
               & (self.vid_index[self.E_srcg] >= 0)
               & (self.vid_index[self.E_dstg] >= 0)) \
@@ -487,14 +509,6 @@ class SnapshotEngine:
         dst_idx = self.vid_index[self.E_dstg[self.f_mask]]
         self.csr_key = np.sort(_sort_key(src_idx, dst_idx))
         self.csc_key = np.sort(_sort_key(dst_idx, src_idx))
-
-        self.at = at
-        self.refine = refine
-        self._valid = True
-        self._vids_copy = None    # a rebuild may change vids at same len
-        self._vids_ver = 0        # bumped on every vids mutation
-        self.stats["cold"] += 1
-        self._make_ga()
 
     def _refresh_views(self) -> None:
         """Re-point the plain-array attributes at their grow buffers."""
@@ -747,6 +761,18 @@ class SnapshotEngine:
             self.at = at
             self.stats["delta_noop"] += 1
             return
+        h = self.weaver.sim.host
+        patched = (self._patch_edges(f_rows) if h is None
+                   else h.call("csr_compact", self._patch_edges, f_rows))
+        self.at = at
+        self.stats["delta"] += 1
+        if patched or v_changed:
+            self._make_ga()
+
+    def _patch_edges(self, f_rows: np.ndarray) -> bool:
+        """Re-evaluate the final edge mask on ``f_rows`` and patch the
+        CSR/CSC keys of the edges that appeared or vanished; returns
+        whether any did."""
         new_f = (self.e_vis[f_rows]
                  & (self.vid_index[self.E_srcg[f_rows]] >= 0)
                  & (self.vid_index[self.E_dstg[f_rows]] >= 0))
@@ -754,21 +780,19 @@ class SnapshotEngine:
         self.f_mask[f_rows] = new_f
         added = f_rows[new_f & ~old_f]
         removed = f_rows[old_f & ~new_f]
-        if added.size or removed.size:
-            a_src = self.vid_index[self.E_srcg[added]]
-            a_dst = self.vid_index[self.E_dstg[added]]
-            r_src = self.vid_index[self.E_srcg[removed]]
-            r_dst = self.vid_index[self.E_dstg[removed]]
-            self.csr_key = _merge_patch(self.csr_key,
-                                        _sort_key(r_src, r_dst),
-                                        _sort_key(a_src, a_dst))
-            self.csc_key = _merge_patch(self.csc_key,
-                                        _sort_key(r_dst, r_src),
-                                        _sort_key(a_dst, a_src))
-        self.at = at
-        self.stats["delta"] += 1
-        if added.size or removed.size or v_changed:
-            self._make_ga()
+        if not (added.size or removed.size):
+            return False
+        a_src = self.vid_index[self.E_srcg[added]]
+        a_dst = self.vid_index[self.E_dstg[added]]
+        r_src = self.vid_index[self.E_srcg[removed]]
+        r_dst = self.vid_index[self.E_dstg[removed]]
+        self.csr_key = _merge_patch(self.csr_key,
+                                    _sort_key(r_src, r_dst),
+                                    _sort_key(a_src, a_dst))
+        self.csc_key = _merge_patch(self.csc_key,
+                                    _sort_key(r_dst, r_src),
+                                    _sort_key(a_dst, a_src))
+        return True
 
     def _drop_vertices(self, rows: np.ndarray) -> None:
         """Remove newly-invisible vertices from the compacted index.
@@ -929,10 +953,17 @@ class SnapshotEngine:
 
     def snapshot(self, at: Stamp, refine_concurrent: bool = True,
                  keep_raw: bool = False) -> GraphArrays:
+        h = self.weaver.sim.host
         if self._delta_ok(at, refine_concurrent):
-            self._refresh(at, refine_concurrent)
-        else:
+            if h is None:
+                self._refresh(at, refine_concurrent)
+            else:
+                h.call("snapshot_delta", self._refresh, at,
+                       refine_concurrent)
+        elif h is None:
             self._cold(at, refine_concurrent)
+        else:
+            h.call("snapshot_cold", self._cold, at, refine_concurrent)
         # vids/index are snapshotted by copy (later deltas mutate the
         # engine's structures, which would leak future vertices into an
         # older snapshot); the copies are cached until the vertex set
@@ -1053,8 +1084,6 @@ def bfs_levels(edge_src: jnp.ndarray, edge_dst: jnp.ndarray,
     """
     if max_iters is None:
         max_iters = n_nodes
-    dist0 = jnp.full((n_nodes,), INF, dtype=jnp.int32)
-    dist0 = dist0.at[sources].set(0)
 
     def cond(state):
         _, i, changed = state
@@ -1070,8 +1099,11 @@ def bfs_levels(edge_src: jnp.ndarray, edge_dst: jnp.ndarray,
         nd = jnp.minimum(dist, relaxed)
         return nd, i + 1, jnp.any(nd != dist)
 
-    dist, _, _ = jax.lax.while_loop(cond, body, (dist0, jnp.int32(0),
-                                                 jnp.bool_(True)))
+    with jax.named_scope("weaver.bfs"):
+        dist0 = jnp.full((n_nodes,), INF, dtype=jnp.int32)
+        dist0 = dist0.at[sources].set(0)
+        dist, _, _ = jax.lax.while_loop(cond, body, (dist0, jnp.int32(0),
+                                                     jnp.bool_(True)))
     return dist
 
 
@@ -1131,19 +1163,19 @@ def connected_components_ga(ga: GraphArrays,
 def pagerank(edge_src, edge_dst, n_nodes: int, n_iters: int = 20,
              damping: float = 0.85, src_sorted: bool = False,
              dst_sorted: bool = False) -> jnp.ndarray:
-    deg = jax.ops.segment_sum(jnp.ones_like(edge_src, dtype=jnp.float32),
-                              edge_src, num_segments=n_nodes,
-                              indices_are_sorted=src_sorted)
-    deg = jnp.maximum(deg, 1.0)
-    pr0 = jnp.full((n_nodes,), 1.0 / n_nodes, dtype=jnp.float32)
-
     def body(_, pr):
         contrib = pr[edge_src] / deg[edge_src]
         agg = jax.ops.segment_sum(contrib, edge_dst, num_segments=n_nodes,
                                   indices_are_sorted=dst_sorted)
         return (1.0 - damping) / n_nodes + damping * agg
 
-    return jax.lax.fori_loop(0, n_iters, body, pr0)
+    with jax.named_scope("weaver.pagerank"):
+        deg = jax.ops.segment_sum(
+            jnp.ones_like(edge_src, dtype=jnp.float32), edge_src,
+            num_segments=n_nodes, indices_are_sorted=src_sorted)
+        deg = jnp.maximum(deg, 1.0)
+        pr0 = jnp.full((n_nodes,), 1.0 / n_nodes, dtype=jnp.float32)
+        return jax.lax.fori_loop(0, n_iters, body, pr0)
 
 
 def pagerank_ga(ga: GraphArrays, n_iters: int = 20,

@@ -923,7 +923,7 @@ class ShardPlan:
 def maintain_plan(plan: Optional[ShardPlan], cols, at: Stamp, n_gk: int,
                   refine_batch: Optional[Callable],
                   allow_delta: bool = True,
-                  device_plane=None
+                  device_plane=None, host=None
                   ) -> Tuple[ShardPlan, str]:
     """The three-way plan maintenance policy, shared by the shard event
     loop (``Shard._frontier_plan``) and the synchronous driver
@@ -937,6 +937,9 @@ def maintain_plan(plan: Optional[ShardPlan], cols, at: Stamp, n_gk: int,
     * ``"cold"``  — a fresh build (first contact, stamp regression, or
       the compaction-event history no longer covers the plan's cursor —
       the stale plan, settled or not, must be discarded).
+
+    ``host`` (a :class:`~repro.core.obs.HostProfile` or None) times the
+    refresh as ``plan_refresh`` and the build as ``plan_build``.
     """
     if plan is not None and plan.cols is cols:
         same = plan.at.key() == at.key()
@@ -944,11 +947,15 @@ def maintain_plan(plan: Optional[ShardPlan], cols, at: Stamp, n_gk: int,
         if plan.version == cols.version and (
                 same or (plan.settled and later)):
             return plan, "reuse"
-        if later and allow_delta and plan.refresh(
-                at, refine_batch=refine_batch):
+        if later and allow_delta and (
+                plan.refresh(at, refine_batch) if host is None
+                else host.call("plan_refresh", plan.refresh, at,
+                               refine_batch)):
             return plan, "delta"
-    return ShardPlan(cols, at, n_gk, refine_batch=refine_batch,
-                     device_plane=device_plane), "cold"
+    if host is None:
+        return ShardPlan(cols, at, n_gk, refine_batch, device_plane), "cold"
+    return host.call("plan_build", ShardPlan, cols, at, n_gk, refine_batch,
+                     device_plane), "cold"
 
 
 def g_len(a: np.ndarray) -> int:

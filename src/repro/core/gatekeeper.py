@@ -329,8 +329,8 @@ class Gatekeeper:
         for sid, shard in enumerate(self.shards):
             self._seq[sid] += 1
             self.sim.counters.nop_messages += 1
-            self.sim.send(self, shard, shard.enqueue, self.gid, self._seq[sid],
-                          stamp, "nop", None, nbytes=8 * self.n_gk + 16)
+            self.sim.send(self, shard, shard.enqueue_nop, self.gid,
+                          self._seq[sid], stamp, nbytes=8 * self.n_gk + 16)
 
     # -- epoch barrier (cluster manager, §4.3) ----------------------------------
     def pause_for_epoch(self) -> None:

@@ -5,8 +5,11 @@ The paper's evaluation (Figs. 9-14) is entirely about *where time goes*
 layer that can answer that for a single request: a :class:`Tracer`
 records causally-linked spans on *simulated* time as requests flow
 through gatekeepers, the store, shards and the timeline oracle; a
-:class:`MetricsRegistry` keeps counters / gauges / histograms plus a
-periodic time series; analysis helpers export Chrome trace-event JSON
+:class:`HostProfile` records where the host's *wall-clock* time goes at
+the same stage boundaries (self time per stage, optionally annotated on
+the JAX profiler's host plane); a :class:`MetricsRegistry` keeps
+counters / gauges / histograms plus a periodic time series; analysis
+helpers export Chrome trace-event JSON
 (loadable in Perfetto / chrome://tracing), attribute a request's
 end-to-end latency to pipeline stages via a critical-path walk over its
 span tree, and check trace-level invariants (exactly-once apply, stamp
@@ -40,10 +43,12 @@ from __future__ import annotations
 
 import json
 from dataclasses import dataclass, field
+from time import perf_counter_ns
 from typing import Any, Callable, Dict, List, Optional, Tuple
 
 __all__ = [
-    "Span", "Tracer", "MetricsRegistry", "OBS_COUNTER_FIELDS",
+    "Span", "Tracer", "HostProfile", "HOST_STAGES", "MetricsRegistry",
+    "OBS_COUNTER_FIELDS", "profiler_active",
     "to_chrome_trace", "validate_trace_events", "span_tree",
     "critical_path", "attribution_table", "format_stage_table",
     "check_exactly_once", "check_stamp_monotonic", "check_completeness",
@@ -52,7 +57,8 @@ __all__ = [
 
 # Counters fields written by the observability layer itself; equivalence
 # tests compare Counters snapshots with these removed.
-OBS_COUNTER_FIELDS = ("spans_recorded", "metrics_samples")
+OBS_COUNTER_FIELDS = ("spans_recorded", "metrics_samples", "host_ns",
+                      "host_events")
 
 
 def stamp_attr(stamp) -> list:
@@ -190,6 +196,234 @@ class Tracer:
 
 
 # ---------------------------------------------------------------------------
+# host wall-clock profile
+# ---------------------------------------------------------------------------
+
+#: Every stage a :class:`HostProfile` records, and its layer (the table in
+#: docs/OBSERVABILITY.md, "Host wall-clock profile").  The first group are
+#: root stages: each simulator event is one, named for the owner of its
+#: handler (:data:`ROOT_STAGES`).  The rest are opened inside handlers and
+#: by the snapshot engine and the column plane, outside the simulator.
+HOST_STAGES: Dict[str, str] = {
+    "gatekeeper": "gatekeepers and timestamp protocol",
+    "oracle": "gatekeepers and timestamp protocol",
+    "shard": "shards and frontier runtime",
+    "coordinator": "shards and frontier runtime",
+    "client": "client / server",
+    "server": "client / server",
+    "gc": "garbage collection",
+    "cluster": "cluster manager",
+    "metrics": "observability",
+    "other": "other",
+    "gk_stamp": "gatekeepers and timestamp protocol",
+    "gk_nop": "gatekeepers and timestamp protocol",
+    "store_commit": "store",
+    "wal_append": "store",
+    "store_gc": "store",
+    "shard_order": "shards and frontier runtime",
+    "shard_apply": "shards and frontier runtime",
+    "shard_nop": "shards and frontier runtime",
+    "shard_gc": "shards and frontier runtime",
+    "frontier_hop": "shards and frontier runtime",
+    "plan_build": "shards and frontier runtime",
+    "plan_refresh": "shards and frontier runtime",
+    "snapshot_delta": "snapshot engine",
+    "snapshot_cold": "snapshot engine",
+    "oracle_refine": "snapshot engine",
+    "csr_compact": "snapshot engine",
+    "plane_sync": "device column plane",
+    "plane_scatter": "device column plane",
+    "plane_launch": "device column plane",
+}
+
+#: Root stage of a simulator event by the qualified name of its handler:
+#: the whole name, else ``Class.method``, else ``Class``; anything else is
+#: ``"other"``.  Handlers that are a named stage in full are named here
+#: (the gatekeeper's NOP/announce timers and receipt, its serve rounds
+#: that stamp, its store leg that validates, a shard's NOP receipt), so
+#: they cost no nested span.
+ROOT_STAGES: Dict[str, str] = {
+    "Gatekeeper": "gatekeeper",
+    "Gatekeeper._send_nops": "gk_nop",
+    "Gatekeeper._announce": "gk_nop",
+    "Gatekeeper.on_announce": "gk_nop",
+    "Gatekeeper.submit_tx.<locals>._go": "gk_stamp",
+    "Gatekeeper.submit_program.<locals>._go": "gk_stamp",
+    "Gatekeeper._flush_timer": "gk_stamp",
+    "Gatekeeper._flush_group": "gk_stamp",
+    "Gatekeeper._rflush_timer": "gk_stamp",
+    "Gatekeeper._flush_rgroup": "gk_stamp",
+    "Gatekeeper._at_store": "gk_stamp",
+    "Gatekeeper._at_store_batch": "gk_stamp",
+    "OracleServer": "oracle",
+    "Shard": "shard",
+    "Shard.enqueue_nop": "shard_nop",
+    "ReplicaShard": "shard",
+    "ProgCoordinator": "coordinator",
+    "Weaver": "client",
+    "Weaver._gc": "gc",
+    "Weaver._sample_metrics": "metrics",
+    "GraphQueryServer": "server",
+    "ClusterManager": "cluster",
+    "HeartbeatSender": "cluster",
+}
+
+_IS_ENABLED: Optional[Callable[[], bool]] = None
+
+
+def profiler_active() -> bool:
+    """True while a JAX profiler trace is capturing host events."""
+    global _IS_ENABLED
+    if _IS_ENABLED is None:
+        from jax.profiler import TraceAnnotation
+        _IS_ENABLED = TraceAnnotation.is_enabled
+    return _IS_ENABLED()
+
+
+class HostProfile:
+    """Wall-clock self time per stage on ``time.perf_counter_ns``.
+
+    Installed as ``sim.host`` (``None`` == off; every site checks that
+    once).  Spans nest on a stack; a span's *self* time is its duration
+    less the time its child spans cover, so the stages of one event sum
+    to the event's wall time.  Totals accumulate in the simulator's
+    ``Counters.host_ns`` / ``Counters.host_events`` (stage -> ns /
+    spans), which :data:`OBS_COUNTER_FIELDS` lists.  With ``annotate``
+    each simulator event, and every span outside one, also opens
+    ``jax.profiler.TraceAnnotation("weaver.<stage>")``, so the stages
+    land on the profiler's host plane, on the clock of the device's
+    operations.
+
+    Sites: ``with h.span(stage): ...`` or ``h.call(stage, fn, *args)``;
+    :meth:`Simulator.run` opens one root span per event
+    (:meth:`root_stage`).
+    """
+
+    def __init__(self, sim, annotate: bool = False):
+        self.ns: Dict[str, int] = sim.counters.host_ns
+        self.events: Dict[str, int] = sim.counters.host_events
+        self.annotate = annotate
+        self._ann_now = annotate         # False inside a simulator event
+        self._stack: List[list] = []     # [stage, t0_ns, child_ns, annotation]
+        self._pending = ""
+        self._roots: Dict[object, str] = {}
+        self._labels: Dict[str, str] = {}
+        from .simulation import PeriodicTimer
+        self._timer_code = PeriodicTimer._fire.__code__
+        if annotate:
+            from jax.profiler import TraceAnnotation
+            self._ann = TraceAnnotation
+
+    # -- recording -------------------------------------------------------
+
+    def _annotation(self, stage: str):
+        label = self._labels.get(stage)
+        if label is None:
+            label = self._labels[stage] = f"weaver.{stage}"
+        ann = self._ann(label)
+        ann.__enter__()
+        return ann
+
+    def begin(self, stage: str) -> None:
+        """Open ``stage`` (closed by :meth:`end`)."""
+        ann = self._annotation(stage) if self._ann_now else None
+        self._stack.append([stage, perf_counter_ns(), 0, ann])
+
+    def end(self) -> int:
+        """Close the innermost span; returns its end (ns)."""
+        t1 = perf_counter_ns()
+        stage, t0, child, ann = self._stack.pop()
+        if ann is not None:
+            ann.__exit__(None, None, None)
+        self._add(stage, t1 - t0, child)
+        return t1
+
+    def span(self, stage: str) -> "HostProfile":
+        """``with h.span(stage):`` — no object is made per call."""
+        self._pending = stage
+        return self
+
+    def __enter__(self) -> None:
+        self.begin(self._pending)
+
+    def __exit__(self, *exc) -> bool:
+        self.end()
+        return False
+
+    def call(self, stage: str, fn: Callable, *args):
+        """``fn(*args)`` inside a ``stage`` span."""
+        ann = self._annotation(stage) if self._ann_now else None
+        stack = self._stack
+        frame = [stage, 0, 0, ann]
+        stack.append(frame)
+        frame[1] = t0 = perf_counter_ns()
+        try:
+            return fn(*args)
+        finally:
+            t1 = perf_counter_ns()
+            stack.pop()
+            if ann is not None:
+                ann.__exit__(None, None, None)
+            self._add(stage, t1 - t0, frame[2])
+
+    def event(self, fn: Callable, args: tuple, t0: int) -> int:
+        """One simulator event: ``fn(*args)`` as a root span of its
+        handler's stage (:meth:`root_stage`) that starts at ``t0``, where
+        the previous event ended; returns its end (ns).  Only the root
+        is annotated: spans inside an event are counted, not annotated,
+        because an annotation costs about as much as a small handler."""
+        stage = self._roots.get(getattr(fn, "__code__", None))
+        if stage is None:                 # first sight, or a timer
+            stage = self.root_stage(fn)
+        ann = self._annotation(stage) if self.annotate else None
+        stack = self._stack
+        frame = [stage, t0, 0, ann]
+        stack.append(frame)
+        outer, self._ann_now = self._ann_now, False
+        try:
+            fn(*args)
+        finally:
+            t1 = perf_counter_ns()
+            self._ann_now = outer
+            stack.pop()
+            if ann is not None:
+                ann.__exit__(None, None, None)
+            self._add(stage, t1 - t0, frame[2])
+        return t1
+
+    def _add(self, stage: str, dur: int, child: int) -> None:
+        try:
+            self.ns[stage] += dur - child
+            self.events[stage] += 1
+        except KeyError:
+            self.ns[stage] = self.ns.get(stage, 0) + dur - child
+            self.events[stage] = self.events.get(stage, 0) + 1
+        if self._stack:
+            self._stack[-1][2] += dur
+
+    # -- root stages -----------------------------------------------------
+
+    def root_stage(self, fn) -> str:
+        """The root stage of an event whose handler is ``fn``, cached per
+        code object; a ``PeriodicTimer`` counts as its function's owner."""
+        code = getattr(fn, "__code__", None)
+        if code is self._timer_code:
+            fn = fn.__self__.fn
+            code = getattr(fn, "__code__", None)
+        stage = self._roots.get(code)
+        if stage is None:
+            fn = getattr(fn, "func", fn)          # functools.partial
+            name = getattr(fn, "__qualname__", "")
+            parts = name.split(".")
+            stage = (ROOT_STAGES.get(name)
+                     or ROOT_STAGES.get(".".join(parts[:2]))
+                     or ROOT_STAGES.get(parts[0], "other"))
+            if code is not None:
+                self._roots[code] = stage
+        return stage
+
+
+# ---------------------------------------------------------------------------
 # metrics
 # ---------------------------------------------------------------------------
 
@@ -263,12 +497,6 @@ class MetricsRegistry:
                 "histograms": {k: {str(b): n for b, n in sorted(v.items())}
                                for k, v in sorted(self.hists.items())},
                 "timeline": self.timeline}
-
-    def hist_snapshot(self, name: str, key_suffix: str = "") -> dict:
-        """Histogram as a plain dict with string bucket keys, e.g.
-        ``{"r:64us": 3}`` for (name="admission_window", suffix="us")."""
-        return {f"{k}{key_suffix}": n
-                for k, n in sorted(self.hists.get(name, {}).items())}
 
 
 # ---------------------------------------------------------------------------

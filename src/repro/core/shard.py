@@ -237,6 +237,12 @@ class Shard:
         # duplicate/old -> drop
         self._kick()
 
+    def enqueue_nop(self, gid: int, seq: int, stamp: Stamp) -> None:
+        """A gatekeeper's NOP (§4.1): an effect-free queue item that lets
+        the heads behind it order; its own handler so that a host profile
+        can tell NOP receipt from writes."""
+        self.enqueue(gid, seq, stamp, "nop", None)
+
     def deliver_prog(self, prog_id: int, delivery_id, name: str, stamp: Stamp,
                      entries: List[Tuple[str, object]], coordinator) -> None:
         if not self.alive:
@@ -424,17 +430,21 @@ class Shard:
         if self.busy or not self.alive:
             return
         self._stall = 0.0
-        # 1) runnable node program? (stamp ≺ every queue head; §4.2)
-        idx = self._runnable_prog_index()
-        if idx is not None:
-            prog = self.pending_progs.pop(idx)
+        h = self.sim.host
+        pick = self._pick() if h is None else h.call("shard_order", self._pick)
+        if pick is None:
+            return                       # idle: wait for the next enqueue/NOP
+        kind, i = pick
+        if kind == "prog":
+            prog = self.pending_progs.pop(i)
             if self.replicated:
                 self._settle_stamp(prog["stamp"])
             extra = self._coalesce_pending(prog) if self.coalesce else []
-            service = self._exec_prog(
-                prog["prog_id"], prog["delivery_id"], prog["name"],
-                prog["stamp"], prog["entries"], prog["coordinator"],
-                extra_ids=extra)
+            args = (prog["prog_id"], prog["delivery_id"], prog["name"],
+                    prog["stamp"], prog["entries"], prog["coordinator"],
+                    extra)
+            service = (self._exec_prog(*args) if h is None
+                       else h.call("frontier_hop", self._exec_prog, *args))
             tr = self.sim.tracer
             if tr is not None:
                 ctx = tr.ctx_for_prog(prog["prog_id"])
@@ -454,20 +464,28 @@ class Shard:
                             actor=self.name, ctx=ctx, shard=self.sid,
                             stamp=st, plan=self._last_plan_kind,
                             depth=getattr(e, "depth", 0), entries=len(e))
-            self._finish_after(service + self._stall)
-            return
-        # 2) transactions: need every queue non-empty (Fig. 6)
+        elif kind == "txbatch":
+            service = (self._exec_batch_prefix(i) if h is None
+                       else h.call("shard_apply", self._exec_batch_prefix, i))
+        else:
+            item = self.queues[i].popleft()
+            service = (self._exec_item(item, i) if h is None or kind == "nop"
+                       else h.call("shard_apply", self._exec_item, item, i))
+        self._finish_after(service + self._stall)
+
+    def _pick(self) -> Optional[Tuple[str, int]]:
+        """What runs next: ``("prog", i)`` for a runnable program (stamp ≺
+        every queue head; §4.2), else ``(kind, g)`` for the gatekeeper
+        queue whose head executes next — transactions need every queue
+        non-empty (Fig. 6) — else None."""
+        i = self._runnable_prog_index()
+        if i is not None:
+            return "prog", i
         if all(self.queues[g] for g in range(self.n_gk)):
-            heads = [(g, self.queues[g][0]) for g in range(self.n_gk)]
-            g = self._order_heads(heads)
-            if self.queues[g][0].kind == "txbatch":
-                service = self._exec_batch_prefix(g)
-            else:
-                item = self.queues[g].popleft()
-                service = self._exec_item(item, g)
-            self._finish_after(service + self._stall)
-            return
-        # idle: wait for the next enqueue/NOP
+            g = self._order_heads([(g, self.queues[g][0])
+                                   for g in range(self.n_gk)])
+            return self.queues[g][0].kind, g
+        return None
 
     def _finish_after(self, service: float) -> None:
         self.busy = True
@@ -834,7 +852,7 @@ class Shard:
             cand, cols, stamp, self.n_gk,
             lambda ss, at=stamp: self._refine_batch(ss, at),
             allow_delta=self.plan_delta,
-            device_plane=self.device_plane)
+            device_plane=self.device_plane, host=self.sim.host)
         self._last_plan_kind = kind or "reuse"
         if kind == "delta":
             ctr.plan_delta_refreshes += 1
@@ -1189,7 +1207,10 @@ class Shard:
             cut = len(self.feed_log) - self.FEED_RETAIN
             del self.feed_log[:cut]
             self.feed_base += cut
-        return self.partition.collect(horizon)
+        h = self.sim.host
+        if h is None:
+            return self.partition.collect(horizon)
+        return h.call("shard_gc", self.partition.collect, horizon)
 
     def recover_from(self, ops: List[dict]) -> None:
         """Backup promotion: rebuild the partition from the store's redo

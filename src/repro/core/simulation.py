@@ -27,10 +27,13 @@ from __future__ import annotations
 
 import heapq
 import itertools
-from dataclasses import dataclass
+from dataclasses import dataclass, field
+from time import perf_counter_ns
 from typing import Any, Callable, Optional
 
 import numpy as np
+
+from .obs import HostProfile, MetricsRegistry, profiler_active
 
 
 @dataclass
@@ -168,6 +171,10 @@ class Counters:
     #                                re-sending the packed values
     spans_recorded: int = 0        # [obs] trace spans recorded
     metrics_samples: int = 0       # [obs] metrics timeline rows sampled
+    host_ns: dict = field(default_factory=dict)      # [obs] host wall-clock
+    #                                self ns per HostProfile stage
+    host_events: dict = field(default_factory=dict)  # [obs] HostProfile
+    #                                spans closed per stage
     cross_pod_msgs: int = 0        # messages that paid the cross-pod
     #                                latency surcharge (sender and
     #                                receiver in different pods)
@@ -212,7 +219,12 @@ class Simulator:
         # optional repro.core.obs.Tracer (None == tracing disabled; every
         # hook site guards on this) and the always-on metrics registry
         self.tracer = None
-        from repro.core.obs import MetricsRegistry
+        # optional repro.core.obs.HostProfile (None == off): wall-clock
+        # self time per stage.  run() installs an annotating one while a
+        # JAX profiler trace is being captured and removes it on its
+        # first call after the capture; one installed by hand stays
+        self.host = None
+        self._host_followed = False
         self.metrics = MetricsRegistry()
         # FIFO enforcement: last scheduled delivery time per (src_id, dst_id)
         self._channel_clock: dict[tuple[int, int], float] = {}
@@ -286,7 +298,14 @@ class Simulator:
 
     # ---- main loop -----------------------------------------------------
     def run(self, until: Optional[float] = None, max_events: int = 50_000_000) -> None:
+        """Execute events up to simulated time ``until``.  Whether to
+        trace and whether to profile the host is decided once per call."""
         self._stopped = False
+        self._follow_profiler()
+        if self.host is not None:
+            self._run_profiled(until, max_events)
+            return
+        tr = self.tracer
         n = 0
         while self._heap and not self._stopped:
             t, _, fn, args, ctx = self._heap[0]
@@ -295,17 +314,52 @@ class Simulator:
                 return
             heapq.heappop(self._heap)
             self.now = t
-            if self.tracer is not None:
-                self.tracer.current = ctx
+            if tr is not None:
+                tr.current = ctx
                 try:
                     fn(*args)
                 finally:
-                    self.tracer.current = None
+                    tr.current = None
             else:
                 fn(*args)
             n += 1
             if n >= max_events:
                 raise RuntimeError(f"simulation exceeded {max_events} events")
+
+    def _run_profiled(self, until: Optional[float], max_events: int) -> None:
+        """The loop with a host profile: each event is one root span,
+        starting where the previous one ended, so every nanosecond of
+        the loop is some stage's."""
+        event, tr = self.host.event, self.tracer
+        n = 0
+        mark = perf_counter_ns()
+        while self._heap and not self._stopped:
+            t, _, fn, args, ctx = self._heap[0]
+            if until is not None and t > until:
+                self.now = until
+                return
+            heapq.heappop(self._heap)
+            self.now = t
+            if tr is not None:
+                tr.current = ctx
+                try:
+                    mark = event(fn, args, mark)
+                finally:
+                    tr.current = None
+            else:
+                mark = event(fn, args, mark)
+            n += 1
+            if n >= max_events:
+                raise RuntimeError(f"simulation exceeded {max_events} events")
+
+    def _follow_profiler(self) -> None:
+        if profiler_active():
+            if self.host is None:
+                self.host = HostProfile(self, annotate=True)
+                self._host_followed = True
+        elif self._host_followed:
+            self.host = None
+            self._host_followed = False
 
     def stop(self) -> None:
         self._stopped = True

@@ -134,7 +134,10 @@ class BackingStore:
         vertex and immediately hangs edges off it).  A logical error aborts
         with no side effects (§4.1).
         """
-        return self._apply_one(ops, ts, log=True, txid=txid)
+        h = self.sim.host
+        if h is None:
+            return self._apply_one(ops, ts, True, txid)
+        return h.call("store_commit", self._apply_one, ops, ts, True, txid)
 
     def apply_batch(self, items: List[Tuple[List[dict], Stamp, object]],
                     torn_limit: Optional[int] = None
@@ -155,6 +158,12 @@ class BackingStore:
         commit; the next entry is written to the log but left beyond the
         record's ``valid`` watermark (a torn tail recovery must
         truncate) and the rest of the window is lost entirely."""
+        h = self.sim.host
+        if h is None:
+            return self._commit_batch(items, torn_limit)
+        return h.call("store_commit", self._commit_batch, items, torn_limit)
+
+    def _commit_batch(self, items, torn_limit: Optional[int]) -> list:
         out = []
         entries: List[Tuple[Stamp, object, List[Tuple[int, dict]]]] = []
         cut = len(items) if torn_limit is None else min(torn_limit, len(items))
@@ -172,6 +181,15 @@ class BackingStore:
             ops, ts, txid = items[cut]
             entries.append((ts, txid, self._torn_fwd(ops, ts)))
             out.extend((False, "torn", None) for _ in items[cut:])
+        h = self.sim.host
+        if h is None:
+            self._append_group(entries, valid)
+        else:
+            h.call("wal_append", self._append_group, entries, valid)
+        return out
+
+    def _append_group(self, entries, valid: int) -> None:
+        """The group's one WAL record, then its outcomes recorded."""
         if entries:
             self.wal.append(WalRecord("group", entries, valid=valid))
             self.sim.counters.wal_records += 1
@@ -181,7 +199,6 @@ class BackingStore:
         # outcomes become answerable to resubmissions exactly now
         for ts, txid, fwd in entries[:valid]:
             self.record_result(txid, True, None, ts, fwd)
-        return out
 
     def _wal_span(self, ts: Stamp, group: bool) -> None:
         """Zero-width durability marker on a sampled trace: the instant
@@ -378,12 +395,21 @@ class BackingStore:
         # last_update the staged writes (or new-vertex creation) set
         self.last_updates.record(self.write_set(ops), ts)
         if log:
-            if fwd:
-                self.wal.append(WalRecord("tx", [(ts, txid, fwd)], valid=1))
-                self.sim.counters.wal_records += 1
-                self._wal_span(ts, group=False)
-            self.record_result(txid, True, None, ts, fwd)
+            h = self.sim.host
+            if h is None:
+                self._append_tx(ts, txid, fwd)
+            else:
+                h.call("wal_append", self._append_tx, ts, txid, fwd)
         return fwd
+
+    def _append_tx(self, ts: Stamp, txid: object,
+                   fwd: List[Tuple[int, dict]]) -> None:
+        """One transaction's WAL record, then its outcome recorded."""
+        if fwd:
+            self.wal.append(WalRecord("tx", [(ts, txid, fwd)], valid=1))
+            self.sim.counters.wal_records += 1
+            self._wal_span(ts, group=False)
+        self.record_result(txid, True, None, ts, fwd)
 
     # ---- touched vertices (for last-update validation) ---------------------
     @staticmethod
@@ -423,6 +449,12 @@ class BackingStore:
           than any client retry session) are pruned.
 
         Returns ``(lastupdate_rows_dropped, vertices_dropped)``."""
+        h = self.sim.host
+        if h is None:
+            return self._collect(horizon)
+        return h.call("store_gc", self._collect, horizon)
+
+    def _collect(self, horizon: Stamp) -> Tuple[int, int]:
         n_rows = self.last_updates.collect(horizon)
         dead = [vid for vid, v in self.vertices.items()
                 if v.delete_ts is not None

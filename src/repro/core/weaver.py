@@ -293,7 +293,8 @@ class Weaver:
         self.device_plane = None
         if cfg.device_shard_columns:
             from ..dist.columns import DeviceColumnPlane
-            self.device_plane = DeviceColumnPlane(cfg.n_gatekeepers)
+            self.device_plane = DeviceColumnPlane(cfg.n_gatekeepers,
+                                                  sim=self.sim)
         self.gatekeepers: List[Gatekeeper] = [
             Gatekeeper(self.sim, g, cfg.n_gatekeepers, self.store, self.oracle,
                        cfg.cost, cfg.tau, cfg.tau_nop,

@@ -64,12 +64,13 @@ def visibility_launch(mesh, use_pallas: bool):
 
     def block_fn(blk, q):
         # blk (spd, 4, B, C) — this device's blocks; q (C,) replicated
-        rows = blk.reshape(-1, blk.shape[-1])
-        if use_pallas:
-            m = ops.before_mask(rows, q, interpret=False)
-        else:
-            m = clock._jnp_before(rows, q)
-        return m.reshape(blk.shape[:-1])
+        with jax.named_scope("weaver.mv_visibility"):
+            rows = blk.reshape(-1, blk.shape[-1])
+            if use_pallas:
+                m = ops.before_mask(rows, q, interpret=False)
+            else:
+                m = clock._jnp_before(rows, q)
+            return m.reshape(blk.shape[:-1])
 
     return jax.jit(jax.shard_map(block_fn, mesh=mesh,
                                  in_specs=(P("data"), P()),
@@ -83,12 +84,15 @@ class DeviceColumnPlane:
     snapshot engine and per-shard plan builds feed from it.  Blocks are
     keyed by column-table identity (``id(cols)`` with a strong ref), so
     dead shards, promoted backups and engine rebuilds never confuse
-    block assignment.
+    block assignment.  ``sim`` (the deployment's simulator) lends its
+    host profile to :meth:`sync` and :meth:`before_all`.
     """
 
-    def __init__(self, n_gk: int, mesh=None, min_block: int = _MIN_BLOCK):
+    def __init__(self, n_gk: int, mesh=None, min_block: int = _MIN_BLOCK,
+                 sim=None):
         import jax
 
+        self.sim = sim
         self.n_gk = n_gk
         self.c = n_gk + 1
         if mesh is None:
@@ -112,11 +116,21 @@ class DeviceColumnPlane:
 
     # ---- residency maintenance -------------------------------------------
 
+    def _host(self):
+        return None if self.sim is None else self.sim.host
+
     def sync(self, shard_cols: Sequence) -> None:
         """Bring resident blocks up to date with each partition's change
         feed.  O(changed) per device for appends/patches; a compaction
         (or an unseen partition / capacity overflow) re-uploads or
         rebuilds."""
+        h = self._host()
+        if h is None:
+            self._sync(shard_cols)
+        else:
+            h.call("plane_sync", self._sync, shard_cols)
+
+    def _sync(self, shard_cols: Sequence) -> None:
         live = [c for c in shard_cols if c is not None]
         fresh = not self._dev
         for cols in live:
@@ -193,7 +207,11 @@ class DeviceColumnPlane:
             ups.append((t0, idx, np.ascontiguousarray(cview[idx])))
             ups.append((t0 + 1, idx, np.ascontiguousarray(dview[idx])))
         if ups:
-            self._scatter(bi, ups)
+            h = self._host()
+            if h is None:
+                self._scatter(bi, ups)
+            else:
+                h.call("plane_scatter", self._scatter, bi, ups)
         self._consumed[bi] = tgt
         self._masks = None
 
@@ -234,13 +252,20 @@ class DeviceColumnPlane:
         """ONE sharded launch answering ``row ≺ q`` for every resident
         block; host-side masks cached until the next mutation or query
         change.  Call after :meth:`sync`."""
+        q = np.asarray(q, np.int32)
+        if self._masks is not None and self._masks_q == q.tobytes():
+            return
+        h = self._host()
+        if h is None:
+            self._launch_masks(q)
+        else:
+            h.call("plane_launch", self._launch_masks, q)
+
+    def _launch_masks(self, q: np.ndarray) -> None:
         import jax
         import jax.numpy as jnp
         from jax.sharding import NamedSharding, PartitionSpec as P
 
-        q = np.asarray(q, np.int32)
-        if self._masks is not None and self._masks_q == q.tobytes():
-            return
         shape = (self.n_dev * self._spd, TABLES, self._cap, self.c)
         sharding = NamedSharding(self.mesh, P("data"))
         garr = jax.make_array_from_single_device_arrays(

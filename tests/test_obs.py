@@ -1,27 +1,38 @@
-"""Deployment-wide observability (ISSUE 9): causal spans, metrics
-timeline, critical-path attribution, trace invariants, and the two
-ride-along optimisations (shared AIMD load signal, read-window
-aliasing + clustering wire dedup).
+"""Deployment-wide observability: causal spans, the host wall-clock
+profile, metrics timeline, critical-path attribution, trace invariants,
+and the two ride-along optimisations (shared AIMD load signal,
+read-window aliasing + clustering wire dedup).
 
 The load-bearing property throughout: tracing is *pure observation*.
-A traced run and an untraced run of the same seeded workload must be
-bit-identical in results and in every counter except the obs tallies
-themselves (``OBS_COUNTER_FIELDS``).
+A traced or host-profiled run and an untraced run of the same seeded
+workload must be bit-identical in results and in every counter except
+the obs tallies themselves (``OBS_COUNTER_FIELDS``).
 """
 
+import heapq
 import json
+import os
+import re
+import time
+from types import SimpleNamespace
 
 import pytest
 
 from repro.core import Weaver, WeaverConfig
+from repro.core import analytics
 from repro.core.faultinject import FaultPlan
-from repro.core.obs import (OBS_COUNTER_FIELDS, attribution_table,
+from repro.core.obs import (HOST_STAGES, OBS_COUNTER_FIELDS, ROOT_STAGES,
+                            HostProfile, attribution_table,
                             check_completeness, check_replica_staleness,
                             export_trace, run_invariant_checks,
                             validate_trace_events)
+from repro.core.simulation import Counters
+
+DOCS = os.path.join(os.path.dirname(os.path.dirname(os.path.abspath(
+    __file__))), "docs", "OBSERVABILITY.md")
 
 
-def _tx_read_workload(rate: float, seed: int = 11):
+def _tx_read_workload(rate: float, seed: int = 11, host: bool = False):
     """The equivalence workload: adaptive admission, bounded queues,
     retry sessions — enough machinery that any tracing side effect
     (an extra event, an RNG draw) would shift results or counters."""
@@ -30,6 +41,8 @@ def _tx_read_workload(rate: float, seed: int = 11):
                        admission_queue_limit=8, read_retry_timeout=4e-3,
                        seed=seed)
     w = Weaver(cfg)
+    if host:
+        w.sim.host = HostProfile(w.sim)
     results = []
     for i in range(30):
         tx = w.begin_tx()
@@ -45,30 +58,177 @@ def _tx_read_workload(rate: float, seed: int = 11):
     return w, results
 
 
+#: the observed side of each purity case: (trace_sample_rate, host
+#: profile installed); the other side is (0.0, False)
+OBSERVED = {"tracer-0.5": (0.5, False), "tracer-1.0": (1.0, False),
+            "host": (0.0, True), "both": (1.0, True)}
+
+
 class TestPureObservation:
-    def test_tracing_changes_nothing(self):
-        """Results and non-obs counters are bit-identical across
-        sampling rates 0.0 / 0.5 / 1.0 on the same seeded workload."""
-        runs = {}
-        for rate in (0.0, 0.5, 1.0):
-            w, results = _tx_read_workload(rate)
+    @pytest.mark.parametrize("mode", list(OBSERVED))
+    def test_tracing_changes_nothing(self, mode):
+        """Results and non-obs counters are bit-identical with the
+        tracer, the host profile, or both, against neither."""
+        runs = []
+        for rate, host in ((0.0, False), OBSERVED[mode]):
+            w, results = _tx_read_workload(rate, host=host)
             c = w.counters()
             for f in OBS_COUNTER_FIELDS:
                 c.pop(f, None)
-            runs[rate] = (results, c)
-        base_res, base_c = runs[0.0]
-        for rate in (0.5, 1.0):
-            res, c = runs[rate]
-            assert res == base_res, f"rate {rate} changed results"
-            diff = {k: (base_c.get(k), c.get(k))
-                    for k in set(base_c) | set(c)
-                    if base_c.get(k) != c.get(k)}
-            assert not diff, f"rate {rate} changed counters: {diff}"
+            runs.append((results, c))
+        (base_res, base_c), (res, c) = runs
+        assert res == base_res, f"{mode} changed results"
+        diff = {k: (base_c.get(k), c.get(k))
+                for k in set(base_c) | set(c)
+                if base_c.get(k) != c.get(k)}
+        assert not diff, f"{mode} changed counters: {diff}"
 
     def test_disabled_tracer_records_nothing(self):
         w, _ = _tx_read_workload(0.0)
         assert w.sim.tracer is None
         assert w.sim.counters.spans_recorded == 0
+
+    def test_host_profile_off_records_nothing(self):
+        """The simulator follows the JAX profiler: with none capturing,
+        no profile is installed and nothing is recorded."""
+        w, _ = _tx_read_workload(0.0)
+        assert w.sim.host is None
+        assert w.counters()["host_ns"] == {}
+        assert w.counters()["host_events"] == {}
+
+
+def _doc_host_stages() -> dict:
+    """Stage -> layer rows of OBSERVABILITY.md's host-profile table."""
+    with open(DOCS) as f:
+        text = f.read()
+    sec = text.split("## Host wall-clock profile", 1)[1].split("\n## ", 1)[0]
+    return dict(re.findall(r"^\| `([a-z_]+)` \| [^|]+ \| ([^|]+?) \|",
+                           sec, re.M))
+
+
+def _host_workload(**kw):
+    """Writes, reads, GC and two snapshots (cold, then delta) through the
+    device column plane, with the host profile on."""
+    cfg = WeaverConfig(write_group_commit=1e-3, read_group_commit=1e-3,
+                       device_shard_columns=True, gc_period=5e-3, seed=21,
+                       **kw)
+    w = Weaver(cfg)
+    w.sim.host = HostProfile(w.sim)
+    tx = w.begin_tx()
+    for i in range(12):
+        tx.create_vertex(f"h{i}")
+    for i in range(11):
+        tx.create_edge(f"h{i}", f"h{i + 1}")
+    assert w.run_tx(tx).ok
+    return w
+
+
+class TestHostProfile:
+    def test_nested_spans_give_self_time(self):
+        sim = SimpleNamespace(counters=Counters())
+        h = HostProfile(sim)
+
+        def handler():
+            time.sleep(0.002)
+            with h.span("inner"):
+                time.sleep(0.003)
+            assert h.call("leaf", lambda x: x + 1, 1) == 2
+
+        t0 = time.perf_counter_ns()
+        t1 = h.event(handler, (), t0)      # a root span from t0
+        ns = sim.counters.host_ns
+        assert ns["inner"] >= 3_000_000
+        assert ns["other"] >= 2_000_000
+        # the children's time is the parent's no more: the stages tile
+        # the root span exactly
+        assert ns["other"] + ns["inner"] + ns["leaf"] == t1 - t0
+        assert sim.counters.host_events == {"other": 1, "inner": 1,
+                                            "leaf": 1}
+        with pytest.raises(ZeroDivisionError):
+            h.call("boom", lambda: 1 / 0)
+        assert h._stack == [] and sim.counters.host_events["boom"] == 1
+
+    def test_every_event_one_root_stage_and_wall_covered(self, monkeypatch):
+        w = _host_workload()
+        sim = w.sim
+        done = []
+        for i in range(10):
+            tx = w.begin_tx()
+            tx.create_edge(f"h{i}", f"h{(i + 5) % 12}")
+            w.submit_tx(tx, done.append)
+            w.submit_program("count_edges", [(f"h{i}", None)],
+                             lambda r, s, l: done.append(r))
+        pops = [0]
+        real = heapq.heappop
+
+        def counting(heap):
+            if heap is sim._heap:
+                pops[0] += 1
+            return real(heap)
+        monkeypatch.setattr(heapq, "heappop", counting)
+        ns0 = dict(sim.counters.host_ns)
+        ev0 = dict(sim.counters.host_events)
+        t0 = time.perf_counter_ns()
+        sim.run(until=sim.now + 30e-3)
+        wall = time.perf_counter_ns() - t0
+        monkeypatch.undo()
+        assert len(done) == 20
+        c = sim.counters
+        ev = {k: v - ev0.get(k, 0) for k, v in c.host_events.items()}
+        ns = {k: v - ns0.get(k, 0) for k, v in c.host_ns.items()}
+        roots = set(ROOT_STAGES.values()) | {"other"}
+        assert pops[0] > 1000
+        assert sum(n for k, n in ev.items() if k in roots) == pops[0]
+        assert abs(sum(ns.values()) - wall) <= 0.02 * wall, (ns, wall)
+
+    def test_every_stage_is_documented(self):
+        w = _host_workload(n_replicas=1)
+        for i in range(4):
+            w.run_program("count_edges", [(f"h{i}", None)])
+        w.settle(20e-3)
+        eng = analytics.SnapshotEngine(w)
+        at = w.run_program("get_node", [("h0", None)])[1]
+        eng.snapshot(at)
+        tx = w.begin_tx()
+        tx.create_edge("h3", "h9")
+        assert w.run_tx(tx).ok
+        eng.snapshot(w.run_program("get_node", [("h0", None)])[1])
+        assert eng.stats["cold"] == 1 and eng.stats["delta"] == 1
+        doc = _doc_host_stages()
+        assert doc == HOST_STAGES
+        assert set(ROOT_STAGES.values()) <= set(HOST_STAGES)
+        seen = set(w.counters()["host_ns"])
+        assert seen <= set(HOST_STAGES), seen - set(HOST_STAGES)
+        assert {"gk_stamp", "gk_nop", "shard_order", "shard_nop",
+                "frontier_hop", "store_commit", "wal_append", "gc",
+                "snapshot_cold", "snapshot_delta", "csr_compact",
+                "plane_sync", "plane_launch"} <= seen, seen
+
+    def test_follows_the_jax_profiler(self, tmp_path):
+        """An annotating profile exists while a profiler trace is
+        captured, and its events' stages land on the host plane as
+        ``weaver.<stage>``."""
+        import jax
+
+        w, _ = _tx_read_workload(0.0)
+        assert w.sim.host is None
+        with jax.profiler.trace(str(tmp_path)):
+            for i in range(3):
+                w.run_program("count_edges", [(f"v{i}", None)])
+            assert w.sim.host is not None and w.sim.host.annotate
+        w.settle(1e-3)
+        assert w.sim.host is None
+        assert w.counters()["host_ns"].get("shard", 0) > 0
+        path = next(os.path.join(d, f) for d, _, fs in os.walk(tmp_path)
+                    for f in fs if f.endswith(".xplane.pb"))
+        pd = jax.profiler.ProfileData.from_file(path)
+        names = {e.name for pl in pd.planes for ln in pl.lines
+                 for e in ln.events}
+        assert {"weaver.shard", "weaver.gk_stamp"} <= names, names
+        # inside an event only the root is annotated; the nested stages
+        # are counted
+        assert "weaver.shard_order" not in names
+        assert w.counters()["host_ns"].get("shard_order", 0) > 0
 
 
 class TestAttribution:
