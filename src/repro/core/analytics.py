@@ -6,8 +6,9 @@ multi-version graph;
 the data plane materializes a *snapshot at a refinable timestamp* as flat
 arrays and runs traversal node programs as frontier message-passing
 (`lax.while_loop` + segment reductions) — the same scatter-gather regime
-as the assigned GNN architectures, so the Pallas kernels
-(`repro.kernels.mv_visibility`, `repro.kernels.segment_mp`) serve both.
+as the assigned GNN architectures.  On the TPU the Pallas kernels
+`repro.kernels.mv_visibility` (snapshot visibility) and
+`repro.kernels.segment_sum` (PageRank's edge pass) serve it.
 
 Columnar snapshot engine
 ------------------------
@@ -1178,12 +1179,68 @@ def pagerank(edge_src, edge_dst, n_nodes: int, n_iters: int = 20,
         return jax.lax.fori_loop(0, n_iters, body, pr0)
 
 
+@partial(jax.jit, static_argnames=("n_nodes", "n_iters", "block_n",
+                                   "block_e"))
+def pagerank_tiled(csc_src, csc_dst, deg, tile_block, tile_chunk, n_used,
+                   n_nodes: int, n_iters: int = 20, damping: float = 0.85,
+                   *, block_n: int, block_e: int) -> jnp.ndarray:
+    """:func:`pagerank` on CSC edges padded to the tile layout's whole
+    chunks (src 0, dst -1), with ``deg`` the floored out-degree.  Per
+    iteration: the per-vertex contribution ``pr / deg``, gathered once
+    per edge (for each edge the value ``pr[src] / deg[src]`` is) by the
+    MXU ``table_gather`` where the table is small enough, else by XLA,
+    and summed per destination by the tiled ``segment_sum`` kernel."""
+    from repro.kernels.segment_sum.kernel import (GATHER_MAX_N,
+                                                  table_gather,
+                                                  tiled_segment_sum)
+
+    def body(_, pr):
+        c = pr / deg
+        vals = (table_gather(c, csc_src, block_e=block_e)
+                if n_nodes <= GATHER_MAX_N else c[csc_src])
+        agg = tiled_segment_sum(vals, csc_dst, tile_block, tile_chunk,
+                                n_used, n_nodes, block_n=block_n,
+                                block_e=block_e)
+        return (1.0 - damping) / n_nodes + damping * agg
+
+    with jax.named_scope("weaver.pagerank"):
+        pr0 = jnp.full((n_nodes,), 1.0 / n_nodes, dtype=jnp.float32)
+        return jax.lax.fori_loop(0, n_iters, body, pr0)
+
+
+#: :func:`pagerank_ga` calls on the tiled kernel path and on the XLA path;
+#: tiles that did work and the edge slots they read but did not count
+pagerank_stats = {"tiled_calls": 0, "xla_calls": 0, "tiles": 0,
+                  "edges": 0, "masked_slots": 0}
+
+
 def pagerank_ga(ga: GraphArrays, n_iters: int = 20,
                 damping: float = 0.85) -> jnp.ndarray:
-    """PageRank over a columnar snapshot: CSC orientation so the per-iter
-    scatter (dst-keyed) claims sorted ids; degree is a one-off."""
-    return pagerank(jnp.asarray(ga.csc_src), jnp.asarray(ga.csc_dst),
-                    ga.n_nodes, n_iters, damping, dst_sorted=True)
+    """PageRank over a columnar snapshot, in its CSC orientation.
+
+    On the TPU the edge pass runs in the ``segment_sum`` kernels
+    (:func:`pagerank_tiled`; tile list and out-degree built once per
+    call on the host); elsewhere in :func:`pagerank` with sorted ids."""
+    n = ga.n_nodes
+    if not (_use_kernel() and n):
+        pagerank_stats["xla_calls"] += 1
+        return pagerank(jnp.asarray(ga.csc_src), jnp.asarray(ga.csc_dst),
+                        n, n_iters, damping, dst_sorted=True)
+    from repro.kernels.segment_sum import ops as seg
+
+    src, dst = ga.csc_src, ga.csc_dst
+    lay = seg.tile_layout(dst, n)
+    deg = np.maximum(np.bincount(src, minlength=n), 1).astype(np.float32)
+    pagerank_stats["tiled_calls"] += 1
+    pagerank_stats["tiles"] += int(lay.n_used[0])
+    pagerank_stats["edges"] += lay.n_edges
+    pagerank_stats["masked_slots"] += lay.masked_slots
+    return pagerank_tiled(
+        jnp.asarray(seg.pad_edges(src, lay, 0)),
+        jnp.asarray(seg.pad_edges(dst, lay, -1)), jnp.asarray(deg),
+        jnp.asarray(lay.tile_block), jnp.asarray(lay.tile_chunk),
+        jnp.asarray(lay.n_used), n, n_iters, damping,
+        block_n=lay.block_n, block_e=lay.block_e)
 
 
 @partial(jax.jit, static_argnames=("n_nodes",))

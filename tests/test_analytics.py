@@ -75,6 +75,54 @@ class TestFrontierPrograms:
         # out-edges; with 300 random edges over 50 nodes that's unlikely
         assert pr.min() > 0
 
+    @pytest.mark.parametrize("gather", ["mxu", "xla"])
+    def test_tiled_pagerank_matches_f64_loop(self, monkeypatch, request,
+                                             gather):
+        """``pagerank_ga`` on the tiled kernel path (interpreted here) on a
+        small R-MAT graph, both directions, plus vertices with no edges
+        and a few with in-edges only (dangling): within float32 of a
+        float64 loop of the same formulation, and counted.  ``xla``: a
+        graph past ``GATHER_MAX_N`` takes XLA's gather."""
+        import jax
+        from repro.kernels.segment_sum import kernel
+        if gather == "xla":
+            monkeypatch.setattr(kernel, "GATHER_MAX_N", 0)
+            # pagerank_tiled is traced anew here and after the test
+            jax.clear_caches()
+            request.addfinalizer(jax.clear_caches)
+        rng = np.random.default_rng(7)
+        scale, n = 9, 600                     # 512 R-MAT ids + 88 more
+        src = np.zeros(8 * (1 << scale), np.int64)
+        dst = np.zeros_like(src)
+        for _ in range(scale):
+            q = rng.choice(4, src.size, p=[0.57, 0.19, 0.19, 0.05])
+            src, dst = 2 * src + q // 2, 2 * dst + q % 2
+        perm = rng.permutation(1 << scale)
+        src, dst = perm[src], perm[dst]
+        src, dst = np.r_[src, dst, [0, 1, 2]], np.r_[dst, src, [597] * 3]
+        ga = A.GraphArrays(vids=list(range(n)), index={},
+                           edge_src=src.astype(np.int32),
+                           edge_dst=dst.astype(np.int32), n_nodes=n)
+        deg = np.maximum(np.bincount(src, minlength=n), 1).astype(float)
+        want = np.full(n, 1.0 / n)
+        for _ in range(20):
+            agg = np.zeros(n)
+            np.add.at(agg, dst, want[src] / deg[src])
+            want = 0.15 / n + 0.85 * agg
+        before = dict(A.pagerank_stats)
+        monkeypatch.setattr(A, "FORCE_KERNEL", True)
+        got = np.asarray(A.pagerank_ga(ga))
+        np.testing.assert_allclose(got, want, rtol=1e-5)
+        after = A.pagerank_stats
+        assert after["tiled_calls"] == before["tiled_calls"] + 1
+        assert after["xla_calls"] == before["xla_calls"]
+        assert after["edges"] - before["edges"] == src.size
+        assert after["tiles"] > before["tiles"]
+        monkeypatch.setattr(A, "FORCE_KERNEL", False)
+        np.testing.assert_allclose(np.asarray(A.pagerank_ga(ga)), want,
+                                   rtol=1e-5)
+        assert A.pagerank_stats["xla_calls"] == before["xla_calls"] + 1
+
     def test_sssp_weighted(self):
         src = np.array([0, 0, 1], dtype=np.int32)
         dst = np.array([1, 2, 2], dtype=np.int32)

@@ -120,6 +120,122 @@ class TestSegmentMP:
                                    rtol=1e-4, atol=1e-4)
 
 
+# -------------------------------------------------------------- segment_sum
+def _sorted_segments(rng, n, e, hub=None, hub_share=0.0, lo=0, hi=None):
+    """dst-sorted ``(vals, dst)``: dst uniform in ``[lo, hi)``, a share of
+    the edges on one ``hub``; vals positive float32."""
+    dst = rng.integers(lo, n if hi is None else hi, e)
+    if hub is not None:
+        dst[rng.random(e) < hub_share] = hub
+    dst = np.sort(dst).astype(np.int32)
+    return rng.random(e).astype(np.float32) + 0.01, dst
+
+
+def _segment_sum_f64(vals, dst, n):
+    out = np.zeros(n)
+    np.add.at(out, dst, vals.astype(np.float64))
+    return out
+
+
+def _tiled_segment_sum(vals, dst, n, bn, be):
+    from repro.kernels.segment_sum import ops
+    from repro.kernels.segment_sum.kernel import tiled_segment_sum
+    lay = ops.tile_layout(dst, n, bn, be)
+    return np.asarray(tiled_segment_sum(
+        jnp.asarray(ops.pad_edges(vals, lay, 0.0)),
+        jnp.asarray(ops.pad_edges(dst, lay, -1)),
+        jnp.asarray(lay.tile_block), jnp.asarray(lay.tile_chunk),
+        jnp.asarray(lay.n_used), n, block_n=bn, block_e=be))
+
+
+class TestSegmentSum:
+    @pytest.mark.parametrize("case,n,e,bn,be", [
+        ("hub", 300, 3000, 128, 256),          # a hub over many tiles
+        ("gaps", 700, 900, 128, 256),          # empty blocks and vertices
+        ("ragged", 1000, 2500, 128, 512),      # E % BE, N % BN both != 0
+        ("no_edges", 200, 0, 128, 256),
+        ("wide", 2048, 6000, 256, 1024),
+    ])
+    def test_matches_f64(self, case, n, e, bn, be):
+        rng = np.random.default_rng(e + n)
+        if case == "hub":
+            vals, dst = _sorted_segments(rng, n, e, hub=131, hub_share=0.6)
+        elif case == "gaps":
+            # edges only into [130, 200) and [500, 510): blocks 2, 4, 5
+            # and most vertices receive nothing
+            v1, d1 = _sorted_segments(rng, n, e - 100, lo=130, hi=200)
+            v2, d2 = _sorted_segments(rng, n, 100, lo=500, hi=510)
+            vals, dst = np.r_[v1, v2], np.r_[d1, d2]
+        else:
+            vals, dst = _sorted_segments(rng, n, e)
+        got = _tiled_segment_sum(vals, dst, n, bn, be)
+        want = _segment_sum_f64(vals, dst, n)
+        np.testing.assert_allclose(got, want, rtol=1e-6)
+        assert np.all(got[want == 0] == 0)
+
+    @pytest.mark.parametrize("n,e,bn,be,hub", [
+        (300, 3000, 128, 256, 131), (1000, 2500, 128, 512, None),
+        (200, 0, 128, 256, None), (5000, 700, 128, 128, 4999)])
+    def test_layout_counts_every_edge_once(self, n, e, bn, be, hub):
+        """Each edge lies in the chunk of exactly one used tile of its own
+        block, within ``T_max`` tiles, and every block has a tile."""
+        from repro.kernels.segment_sum import ops
+        rng = np.random.default_rng(n + e)
+        _, dst = _sorted_segments(rng, n, e, hub=hub, hub_share=0.5)
+        lay = ops.tile_layout(dst, n, bn, be)
+        n_blocks = -(-n // bn)
+        assert lay.tile_block.size == -(-e // be) + n_blocks
+        used = int(lay.n_used[0])
+        tb, tc = lay.tile_block[:used], lay.tile_chunk[:used]
+        assert np.all(np.diff(tb) >= 0)
+        assert set(tb.tolist()) == set(range(n_blocks))
+        assert not np.any((np.diff(tb) == 0) & (np.diff(tc) <= 0))
+        assert np.all((tc >= 0) & (tc * be < lay.e_pad))
+        hits = np.zeros(e, np.int64)
+        for b, c in zip(tb.tolist(), tc.tolist()):
+            pos = np.arange(c * be, min((c + 1) * be, e))
+            hits[pos[dst[pos] // bn == b]] += 1
+        np.testing.assert_array_equal(hits, 1)
+        assert lay.masked_slots == used * be - e
+
+    @pytest.mark.parametrize("n,e,be", [(3000, 2048, 1024), (1, 256, 128),
+                                        (700, 512, 256),
+                                        (1 << 17, 1024, 1024)])
+    def test_table_gather_is_bit_equal(self, n, e, be):
+        """The MXU gather returns ``table[idx]`` bit for bit, for values
+        over many binades, zero, the largest float and a table whose size
+        is not a multiple of 128."""
+        from repro.kernels.segment_sum.kernel import table_gather
+        rng = np.random.default_rng(n + e)
+        table = ((1 + rng.random(n)) * 2.0 ** rng.integers(-103, 127, n)
+                 ).astype(np.float32)
+        table[0] = 0.0
+        table[-1] = np.finfo(np.float32).max
+        idx = rng.integers(0, n, e).astype(np.int32)
+        idx[:2] = [0, n - 1]
+        got = np.asarray(table_gather(jnp.asarray(table), jnp.asarray(idx),
+                                      block_e=be))
+        np.testing.assert_array_equal(got.view(np.int32),
+                                      table[idx].view(np.int32))
+
+    def test_split_bf16_parts_sum_exactly(self):
+        from repro.kernels.segment_sum.kernel import split_bf16
+        rng = np.random.default_rng(3)
+        # down to 2**-103, below which the last part may be subnormal
+        x = np.r_[(1 + rng.random(4096)) * 2.0 ** rng.integers(-103, 127,
+                                                               4096),
+                  2.0 ** -103,
+                  -rng.random(64), 0.0, 1.0 - 2.0 ** -24].astype(np.float32)
+        p = np.asarray(split_bf16(jnp.asarray(x))).astype(np.float32)
+        np.testing.assert_array_equal(((p[2] + p[1]) + p[0]).view(np.int32),
+                                      x.view(np.int32))
+
+    def test_layout_refuses_unsorted_dst(self):
+        from repro.kernels.segment_sum import ops
+        with pytest.raises(ValueError, match="non-decreasing"):
+            ops.tile_layout(np.array([3, 1, 2], np.int32), 4)
+
+
 # ---------------------------------------------------------- flash_attention
 class TestFlashAttention:
     @pytest.mark.parametrize("bh,sq,sk,d,causal,window,dtype", [

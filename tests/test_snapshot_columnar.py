@@ -277,8 +277,10 @@ class TestSortedTraversalHelpers:
                                        ga.n_nodes, jnp.asarray([src_i])))
         np.testing.assert_array_equal(got, want)
 
-    def test_pagerank_ga_matches_plain(self):
+    @pytest.mark.parametrize("tiled", [False, True])
+    def test_pagerank_ga_matches_plain(self, tiled, monkeypatch):
         ga = self._snapshot(seed=1)
+        monkeypatch.setattr(A, "FORCE_KERNEL", tiled)
         got = np.asarray(A.pagerank_ga(ga))
         want = np.asarray(A.pagerank(jnp.asarray(ga.edge_src),
                                      jnp.asarray(ga.edge_dst), ga.n_nodes))

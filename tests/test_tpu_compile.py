@@ -17,11 +17,19 @@ import jax.numpy as jnp
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P, \
     SingleDeviceSharding
 
+from bench.harness.kernel_cost import mv_visibility_bytes
+from repro.core.analytics import pagerank_tiled
 from repro.dist.columns import TABLES, visibility_launch
+from repro.kernels import backend
 from repro.kernels.mv_visibility.kernel import before_pallas, \
     visibility_pallas
 from repro.kernels.segment_mp.kernel import DEFAULT_BLOCK_E, \
     DEFAULT_BLOCK_N, segment_mp_pallas
+from repro.kernels.segment_sum.kernel import GATHER_MAX_N, table_gather, \
+    tiled_segment_sum
+
+#: the analytics cell's snapshot: R-MAT scale 15, 440k undirected edges
+ANALYTICS_N, ANALYTICS_E = 32_768, 880_000
 
 #: rows per (table, shard) block of the smoke deployment: 1M edges over
 #: 8 shards rounds up to 2^18, and 2^20 is the per-launch row count
@@ -105,3 +113,53 @@ def test_column_plane_launch_compiles_on_four_chips(topo):
     per_chip = n_shards * TABLES * SMOKE_BLOCK * c * 4 // 4
     assert per_chip <= compiled.memory_analysis().argument_size_in_bytes \
         < per_chip + 1024
+
+
+@pytest.mark.parametrize("block_n,block_e", [(256, 1024), (128, 2048)])
+@pytest.mark.parametrize("what", ["kernel", "pagerank"])
+def test_segment_sum_compiles_at_analytics_shapes(one_chip, monkeypatch,
+                                                  what, block_n, block_e):
+    """The tiled segment sum alone and inside ``pagerank_tiled`` at the
+    analytics cell's shapes; its launch is not an ``mv_visibility`` one,
+    so ``mv_visibility_roofline`` keeps reading only those."""
+    # the process runs on the CPU: make the kernel's interpret=None
+    # resolve as it does on the chip
+    monkeypatch.setattr(backend, "platform", lambda: "tpu")
+    n, e = ANALYTICS_N, ANALYTICS_E
+    e_pad = -(-e // block_e) * block_e
+    t_max = -(-e // block_e) + -(-n // block_n)
+    spec = lambda shape, dt: jax.ShapeDtypeStruct(shape, dt,
+                                                  sharding=one_chip)
+    tiles = (spec((t_max,), jnp.int32), spec((t_max,), jnp.int32),
+             spec((1,), jnp.int32))
+    if what == "kernel":
+        fn = lambda v, d, tb, tc, nu: tiled_segment_sum(
+            v, d, tb, tc, nu, n, block_n=block_n, block_e=block_e,
+            interpret=False)
+        args = (spec((e_pad,), jnp.float32), spec((e_pad,), jnp.int32))
+    else:
+        fn = lambda s, d, deg, tb, tc, nu: pagerank_tiled(
+            s, d, deg, tb, tc, nu, n, 20, 0.85, block_n=block_n,
+            block_e=block_e)
+        args = (spec((e_pad,), jnp.int32), spec((e_pad,), jnp.int32),
+                spec((n,), jnp.float32))
+    compiled = jax.jit(fn).lower(*args, *tiles).compile()
+    launches = [ln.strip().removeprefix("ROOT ")
+                for ln in compiled.as_text().splitlines()
+                if "tpu_custom_call" in ln]
+    names = {"kernel": ["%tiled_segment_sum"],
+             "pagerank": ["%table_gather", "%tiled_segment_sum"]}[what]
+    assert sorted(ln.split(".", 1)[0] for ln in launches) == names
+    assert all(mv_visibility_bytes(ln) is None for ln in launches)
+
+
+@pytest.mark.parametrize("n", [ANALYTICS_N, GATHER_MAX_N])
+def test_table_gather_compiles(one_chip, n):
+    """The MXU gather at the cell's table and at the largest it takes
+    (VMEM holds the three-part table and the one-hot)."""
+    e = -(-ANALYTICS_E // 1024) * 1024
+    table = jax.ShapeDtypeStruct((n,), jnp.float32, sharding=one_chip)
+    idx = jax.ShapeDtypeStruct((e,), jnp.int32, sharding=one_chip)
+    fn = lambda t, i: table_gather(t, i, interpret=False)
+    compiled = jax.jit(fn).lower(table, idx).compile()
+    assert "tpu_custom_call" in compiled.as_text()
