@@ -114,7 +114,7 @@ verts = sorted({v for e in edges for v in e})
 
 def build(flag):
     w = Weaver(WeaverConfig(n_gatekeepers=N_GK, n_shards=N_SHARDS,
-                            gc_period=0, seed=0,
+                            n_chips=DEVICES, gc_period=0, seed=0,
                             device_shard_columns=flag))
     sg = SG()
     part = lambda v: w.shards[w.store.place(v)].partition

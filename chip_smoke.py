@@ -95,12 +95,12 @@ class ReadChecker:
         return _cb
 
 
-def deployment(seed: int):
+def deployment(seed: int, n_chips: int = 1):
     from repro.configs import PAPER_DEPLOYMENT
     from repro.core import Weaver
 
     return Weaver(dataclasses.replace(
-        PAPER_DEPLOYMENT, device_shard_columns=True,
+        PAPER_DEPLOYMENT, device_shard_columns=True, n_chips=n_chips,
         write_group_commit=WRITE_WINDOW, seed=seed))
 
 
@@ -324,7 +324,7 @@ def mesh_phase(seed: int, n_chips: int, n_users: int = N_USERS) -> dict:
 
     rng = np.random.default_rng(seed)
     edges = synth.social_graph(rng, n_users=n_users, avg_degree=AVG_DEGREE)
-    w = deployment(seed)
+    w = deployment(seed, n_chips)
     t0 = time.perf_counter()
     vertices = load(w, edges)
     log(f"loaded {len(vertices)} vertices, {len(edges)} edges in "

@@ -94,11 +94,20 @@ class GraphArrays:
     raw_src: Optional[np.ndarray] = None
     raw_dst: Optional[np.ndarray] = None
 
+    # the deployment's column-plane mesh (None without a plane): over
+    # more than one device, pagerank_ga and bfs_levels_ga split by
+    # destination range over it; the simulator lends its host profile
+    # and counters to that path
+    mesh: Optional[object] = None
+    sim: Optional[object] = None
+
     # lazily-derived views: CSC orientation ((dst<<32|src) keys from the
-    # engine) and CSR row starts
+    # engine), CSR row starts, and the CSC split over ``mesh`` on its
+    # devices
     _csc_key: Optional[np.ndarray] = None
     _csc: Optional[tuple] = None
     _indptr: Optional[np.ndarray] = None
+    _split: Optional[tuple] = None
 
     @property
     def csc_src(self) -> np.ndarray:
@@ -979,7 +988,9 @@ class SnapshotEngine:
             vids=self._vids_copy, index=self._index_copy,
             edge_src=self.ga.edge_src, edge_dst=self.ga.edge_dst,
             n_nodes=self.ga.n_nodes, _csc_key=self.ga._csc_key,
-            _csc=self.ga._csc, _indptr=self.ga._indptr)
+            _csc=self.ga._csc, _indptr=self.ga._indptr,
+            mesh=None if self.plane is None else self.plane.mesh,
+            sim=self.weaver.sim)
         if keep_raw:
             self._attach_raw(ga)
         return ga
@@ -1108,9 +1119,71 @@ def bfs_levels(edge_src: jnp.ndarray, edge_dst: jnp.ndarray,
     return dist
 
 
+@partial(jax.jit, static_argnames=("n_nodes", "max_iters", "mesh",
+                                   "n_local"))
+def bfs_split(src, dst, sources, n_nodes: int,
+              max_iters: Optional[int] = None, *, mesh,
+              n_local: int) -> jnp.ndarray:
+    """:func:`bfs_levels` split by destination range over ``mesh``'s
+    ``"data"`` devices, on the slices of
+    :func:`repro.kernels.segment_sum.ops.split_layout`: every level each
+    chip relaxes its own edges into its own ``n_local`` vertices, then an
+    all-gather of the levels and a ``psum`` of the chips' "changed" flags
+    exchange them.  The same levels as :func:`bfs_levels`."""
+    from jax.sharding import PartitionSpec as P
+
+    if max_iters is None:
+        max_iters = n_nodes
+
+    def chip(src, dst, dist0):
+        # edges of the straddled chunks outside the range, and padding,
+        # relax nothing; clipping keeps the segment ids sorted
+        inside = (dst >= 0) & (dst < n_local)
+        seg = jnp.clip(dst, 0, n_local - 1)
+        base = jax.lax.axis_index("data") * n_local
+
+        def cond(state):
+            _, i, changed = state
+            return jnp.logical_and(changed, i < max_iters)
+
+        def body(state):
+            dist, i, _ = state
+            d_src = dist[src]
+            cand = jnp.where(inside & (d_src < INF), d_src + 1, INF)
+            relaxed = jax.ops.segment_min(cand, seg, num_segments=n_local,
+                                          indices_are_sorted=True)
+            mine = jax.lax.dynamic_slice(dist, (base,), (n_local,))
+            nd = jnp.minimum(mine, relaxed)
+            with jax.named_scope("weaver.exchange"):
+                changed = jax.lax.psum(
+                    jnp.any(nd != mine).astype(jnp.int32), "data") > 0
+                dist = jax.lax.all_gather(nd, "data", tiled=True)
+            return dist, i + 1, changed
+
+        dist, _, _ = jax.lax.while_loop(cond, body, (dist0, jnp.int32(0),
+                                                     jnp.bool_(True)))
+        return dist
+
+    with jax.named_scope("weaver.bfs"):
+        dist0 = jnp.full((n_local * mesh.size,), INF, dtype=jnp.int32)
+        dist0 = dist0.at[sources].set(0)
+        dist = jax.shard_map(chip, mesh=mesh,
+                             in_specs=(P("data"), P("data"), P()),
+                             out_specs=P(), check_vma=False)(src, dst, dist0)
+    return dist[:n_nodes]
+
+
 def bfs_levels_ga(ga: GraphArrays, sources,
                   max_iters: Optional[int] = None) -> jnp.ndarray:
-    """BFS over a columnar snapshot, exploiting its CSC sort order."""
+    """BFS over a columnar snapshot, exploiting its CSC sort order;
+    split by destination range (:func:`bfs_split`) where ``ga.mesh``
+    spans more than one device."""
+    mesh = _split_mesh(ga)
+    if mesh is not None:
+        lay, a = _split_arrays(ga, mesh)
+        return bfs_split(a["src"], a["dst"], jnp.asarray(sources),
+                         ga.n_nodes, max_iters, mesh=mesh,
+                         n_local=lay.n_local)
     return bfs_levels(jnp.asarray(ga.csc_src), jnp.asarray(ga.csc_dst),
                       ga.n_nodes, jnp.asarray(sources), max_iters,
                       dst_sorted=True)
@@ -1208,6 +1281,97 @@ def pagerank_tiled(csc_src, csc_dst, deg, tile_block, tile_chunk, n_used,
         return jax.lax.fori_loop(0, n_iters, body, pr0)
 
 
+@partial(jax.jit, static_argnames=("n_nodes", "n_iters", "mesh",
+                                   "block_n", "block_e"))
+def pagerank_split(src, dst, deg, tile_block, tile_chunk, n_used,
+                   n_nodes: int, n_iters: int = 20, damping: float = 0.85,
+                   *, mesh, block_n: int, block_e: int) -> jnp.ndarray:
+    """:func:`pagerank_tiled` split by destination range over ``mesh``'s
+    ``"data"`` devices, on
+    :func:`repro.kernels.segment_sum.ops.split_layout`'s slices and tile
+    lists, with ``deg`` the floored out-degree over the padded vertices,
+    held whole by every chip.  Per iteration each chip gathers
+    ``pr / deg`` over its own edges, sums them into its own range, and
+    one all-gather puts the ranks back together.  Each chip reads the
+    whole-graph layout's chunks at their places, so every sum is added
+    in the same order: bit-equal to :func:`pagerank_tiled`."""
+    from jax.sharding import PartitionSpec as P
+
+    from repro.kernels.segment_sum.kernel import (GATHER_MAX_N,
+                                                  table_gather,
+                                                  tiled_segment_sum)
+
+    n_pad = deg.shape[0]
+    n_local = n_pad // mesh.size
+
+    def chip(src, dst, tile_block, tile_chunk, n_used, deg, damping):
+        def body(_, pr):
+            c = pr / deg
+            vals = (table_gather(c, src, block_e=block_e)
+                    if n_pad <= GATHER_MAX_N else c[src])
+            agg = tiled_segment_sum(vals, dst, tile_block, tile_chunk,
+                                    n_used, n_local, block_n=block_n,
+                                    block_e=block_e)
+            mine = (1.0 - damping) / n_nodes + damping * agg
+            with jax.named_scope("weaver.exchange"):
+                return jax.lax.all_gather(mine, "data", tiled=True)
+
+        pr0 = jnp.full((n_pad,), 1.0 / n_nodes, dtype=jnp.float32)
+        return jax.lax.fori_loop(0, n_iters, body, pr0)
+
+    with jax.named_scope("weaver.pagerank"):
+        pr = jax.shard_map(chip, mesh=mesh,
+                           in_specs=(P("data"),) * 5 + (P(), P()),
+                           out_specs=P(), check_vma=False)(
+            src, dst, tile_block, tile_chunk, n_used, deg, damping)
+    return pr[:n_nodes]
+
+
+def _split_mesh(ga: GraphArrays):
+    """``ga.mesh`` where it spans more than one device, else None."""
+    mesh = ga.mesh
+    if mesh is None or mesh.size < 2 or not ga.n_nodes:
+        return None
+    return mesh
+
+
+def _split_arrays(ga: GraphArrays, mesh):
+    """``ga``'s CSC split by destination range over ``mesh``
+    (:func:`repro.kernels.segment_sum.ops.split_layout`) and the
+    arguments of :func:`pagerank_split` and :func:`bfs_split` on its
+    devices, made once per snapshot in the host profile's
+    ``kernel_layout`` stage; each call is counted in the deployment's
+    ``split_*`` counters."""
+    if ga._split is None:
+        h = None if ga.sim is None else ga.sim.host
+        ga._split = (_put_split(ga, mesh) if h is None
+                     else h.call("kernel_layout", _put_split, ga, mesh))
+    lay, args = ga._split
+    if ga.sim is not None:
+        c = ga.sim.counters
+        c.split_calls += 1
+        c.split_chips += mesh.size
+        c.split_edge_slots += int(lay.edges.sum())
+        c.split_edge_slots_max += int(lay.edges.max())
+    return lay, args
+
+
+def _put_split(ga: GraphArrays, mesh):
+    from jax.sharding import NamedSharding, PartitionSpec as P
+
+    from repro.kernels.segment_sum import ops as seg
+
+    n, src = ga.n_nodes, ga.csc_src
+    lay = seg.split_layout(src, ga.csc_dst, n, mesh.size)
+    deg = np.ones(lay.n_local * mesh.size, np.float32)
+    deg[:n] = np.maximum(np.bincount(src, minlength=n), 1)
+    chips, every = NamedSharding(mesh, P("data")), NamedSharding(mesh, P())
+    args = {k: jax.device_put(getattr(lay, k), chips)
+            for k in ("src", "dst", "tile_block", "tile_chunk", "n_used")}
+    args["deg"] = jax.device_put(deg, every)
+    return lay, args
+
+
 #: :func:`pagerank_ga` calls on the tiled kernel path and on the XLA path;
 #: tiles that did work and the edge slots they read but did not count
 pagerank_stats = {"tiled_calls": 0, "xla_calls": 0, "tiles": 0,
@@ -1220,8 +1384,18 @@ def pagerank_ga(ga: GraphArrays, n_iters: int = 20,
 
     On the TPU the edge pass runs in the ``segment_sum`` kernels
     (:func:`pagerank_tiled`; tile list and out-degree built once per
-    call on the host); elsewhere in :func:`pagerank` with sorted ids."""
+    call on the host); elsewhere in :func:`pagerank` with sorted ids.
+    Where ``ga.mesh`` spans more than one device, split by destination
+    range over it (:func:`pagerank_split`, the same kernels, interpreted
+    on the CPU)."""
     n = ga.n_nodes
+    mesh = _split_mesh(ga)
+    if mesh is not None:
+        lay, a = _split_arrays(ga, mesh)
+        return pagerank_split(
+            a["src"], a["dst"], a["deg"], a["tile_block"], a["tile_chunk"],
+            a["n_used"], n, n_iters, damping, mesh=mesh,
+            block_n=lay.block_n, block_e=lay.block_e)
     if not (_use_kernel() and n):
         pagerank_stats["xla_calls"] += 1
         return pagerank(jnp.asarray(ga.csc_src), jnp.asarray(ga.csc_dst),

@@ -509,18 +509,34 @@ class Gatekeeper:
         answered from ``store.tx_results`` (re-forwarding the committed
         slices in case the crash ate them); one already being validated
         is dropped (the session's next timeout covers the race).
-        Internal retries keep their in-flight claim fresh instead.
+        Internal retries keep their in-flight claim fresh instead, unless
+        another attempt decided the txid meanwhile (a resubmission that
+        found the claim stale): they are answered from the table too.
         Returns True when the submission was consumed here."""
         if txid is None:
             return False
         if retries > 0:
-            self.store.touch_inflight(txid)
-            return False
-        verdict = self.store.begin_tx_attempt(txid)
-        if verdict == "inflight":
-            return True
-        if verdict != "done":
-            return False
+            if not self._decided(txid):
+                self.store.touch_inflight(txid)
+                return False
+        else:
+            verdict = self.store.begin_tx_attempt(txid)
+            if verdict == "inflight":
+                return True
+            if verdict != "done":
+                return False
+        self._answer_decided(client, reply, txid)
+        return True
+
+    def _decided(self, txid) -> bool:
+        """Whether some attempt of ``txid`` has recorded its outcome;
+        asked again at the durability point, since two attempts can both
+        pass the gate before either commits."""
+        return txid is not None and txid in self.store.tx_results
+
+    def _answer_decided(self, client, reply, txid) -> None:
+        """Answer an attempt of a decided txid from ``store.tx_results``
+        instead of committing it a second time."""
         self.sim.counters.tx_dedup_hits += 1
         ok, err, stamp, fwd, _ = self.store.tx_results[txid]
         if ok and fwd:
@@ -530,7 +546,6 @@ class Gatekeeper:
             self._forward(stamp, fwd)
         self._reply_after_apply(client, reply, ok, err, stamp,
                                 fwd if ok else None)
-        return True
 
     def _forward(self, stamp, fwd) -> None:
         """Send one committed tx's per-shard slices."""
@@ -664,6 +679,9 @@ class Gatekeeper:
                 if fresh:
                     _refine_then(fresh, self.cost.oracle_rtt)
                     return
+            if self._decided(txid):
+                self._answer_decided(client, reply, txid)
+                return
             try:
                 fwd = self.store.apply(ops, stamp, txid=txid)
             except ValueError as e:        # logical error -> abort, not forwarded
@@ -807,6 +825,9 @@ class Gatekeeper:
                 if residue:
                     _refine_then(residue, self.cost.oracle_rtt, live_idx)
                     return
+            for i in [i for i in live_idx if self._decided(batch[i][6])]:
+                self._answer_decided(batch[i][0], batch[i][3], batch[i][6])
+                live_idx.remove(i)
             if not live_idx:
                 return
             torn = None

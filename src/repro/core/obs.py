@@ -234,6 +234,7 @@ HOST_STAGES: Dict[str, str] = {
     "plane_sync": "device column plane",
     "plane_scatter": "device column plane",
     "plane_launch": "device column plane",
+    "kernel_layout": "graph kernels",
 }
 
 #: Root stage of a simulator event by the qualified name of its handler:

@@ -197,6 +197,14 @@ class Counters:
     replica_promotions: int = 0    # failovers that promoted a caught-up
     #                                replica (partition adopted, WAL
     #                                top-up instead of full replay)
+    split_calls: int = 0           # PageRank and BFS calls split by
+    #                                destination range over the chips
+    #                                (analytics.pagerank_ga, bfs_levels_ga)
+    split_chips: int = 0           # chips, summed over those calls
+    split_edge_slots: int = 0      # CSC edges into the chips' ranges,
+    #                                summed over chips and calls
+    split_edge_slots_max: int = 0  # the largest chip's edges, summed
+    #                                over calls
 
     def snapshot(self) -> dict:
         return {k: (dict(v) if isinstance(v, dict) else v)

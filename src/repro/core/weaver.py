@@ -160,6 +160,10 @@ class ProgCoordinator:
 class WeaverConfig:
     n_gatekeepers: int = 2
     n_shards: int = 4
+    n_chips: int = 1             # devices the column plane spans (its
+    #                              shards' blocks spread evenly) and the
+    #                              graph kernels over its snapshots split
+    #                              by destination range
     tau: float = 1e-3            # vector-clock announce period (§3.3)
     tau_nop: float = 0.5e-3      # NOP period (§4.1)
     gc_period: float = 50e-3     # distributed GC cadence (§4.5)
@@ -271,6 +275,23 @@ class WeaverConfig:
     heartbeat_period: float = 5e-3
 
 
+def _columns_mesh(cfg: WeaverConfig):
+    """The column plane's mesh: exactly ``cfg.n_chips`` local devices,
+    each holding ``n_shards / n_chips`` shards' blocks."""
+    import jax
+
+    from ..launch.mesh import make_columns_mesh
+
+    seen = len(jax.devices())
+    if cfg.n_chips < 1 or cfg.n_chips > seen:
+        raise ValueError(f"n_chips={cfg.n_chips} but {seen} devices are "
+                         f"visible")
+    if cfg.n_shards % cfg.n_chips:
+        raise ValueError(f"n_shards={cfg.n_shards} is not a multiple of "
+                         f"n_chips={cfg.n_chips}")
+    return make_columns_mesh(cfg.n_chips)
+
+
 class Weaver:
     def __init__(self, cfg: WeaverConfig = WeaverConfig()):
         self.cfg = cfg
@@ -293,8 +314,8 @@ class Weaver:
         self.device_plane = None
         if cfg.device_shard_columns:
             from ..dist.columns import DeviceColumnPlane
-            self.device_plane = DeviceColumnPlane(cfg.n_gatekeepers,
-                                                  sim=self.sim)
+            self.device_plane = DeviceColumnPlane(
+                cfg.n_gatekeepers, _columns_mesh(cfg), sim=self.sim)
         self.gatekeepers: List[Gatekeeper] = [
             Gatekeeper(self.sim, g, cfg.n_gatekeepers, self.store, self.oracle,
                        cfg.cost, cfg.tau, cfg.tau_nop,

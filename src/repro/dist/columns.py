@@ -80,7 +80,8 @@ def visibility_launch(mesh, use_pallas: bool):
 class DeviceColumnPlane:
     """Device-resident stamp blocks + one sharded visibility launch.
 
-    One plane per deployment (``Weaver.device_plane``); both the
+    One plane per deployment (``Weaver.device_plane``), on the
+    ``WeaverConfig.n_chips`` devices of ``mesh``; both the
     snapshot engine and per-shard plan builds feed from it.  Blocks are
     keyed by column-table identity (``id(cols)`` with a strong ref), so
     dead shards, promoted backups and engine rebuilds never confuse
@@ -88,16 +89,11 @@ class DeviceColumnPlane:
     host profile to :meth:`sync` and :meth:`before_all`.
     """
 
-    def __init__(self, n_gk: int, mesh=None, min_block: int = _MIN_BLOCK,
+    def __init__(self, n_gk: int, mesh, min_block: int = _MIN_BLOCK,
                  sim=None):
-        import jax
-
         self.sim = sim
         self.n_gk = n_gk
         self.c = n_gk + 1
-        if mesh is None:
-            from ..launch.mesh import make_columns_mesh
-            mesh = make_columns_mesh()
         self.mesh = mesh
         self.n_dev = int(np.prod(list(mesh.shape.values())))
         self._devices = list(mesh.devices.flat)

@@ -218,6 +218,46 @@ class TestClientSession:
         assert len(acked) == 10
 
 
+class TestExactlyOnce:
+    @pytest.mark.parametrize("group", [0.0, 0.5e-3])
+    @pytest.mark.parametrize("overlap", [False, True])
+    def test_internal_retry_and_resubmission_commit_once(self, group,
+                                                        overlap):
+        """A gatekeeper's internal retry (``retries > 0``) of a txid
+        that a client resubmission, past the store's stale in-flight
+        claim, also carries commits once: whichever attempt comes second
+        is answered from the recorded outcome, whether it reaches the
+        gate after the first committed or while both are in flight."""
+        w = make_weaver(write_group_commit=group)
+        seed_hub(w)
+        tx = w.begin_tx()
+        tx.create_vertex("x0")
+        tx.create_edge("x0", "hub")
+        replies = []
+        c = w.sim.counters
+        committed = c.tx_committed
+
+        def attempt(gk, retries):
+            args = (w, tx.ops, gk._tick(),
+                    lambda ok, err, stamp: replies.append((ok, err)),
+                    retries, w.sim.now, "dup-tx")
+            if group:
+                gk._at_store_batch([args])
+            else:
+                gk._at_store(*args)
+
+        attempt(w.gatekeepers[0], 0)     # the resubmission
+        if not overlap:
+            w.settle(0.05)
+        attempt(w.gatekeepers[1], 1)     # the older attempt's retry
+        w.settle(0.05)
+        assert replies == [(True, None), (True, None)]
+        assert c.tx_committed - committed == 1
+        assert c.tx_dedup_hits == 1
+        assert w.store.tx_results["dup-tx"][0] is True
+        assert len(w.store.vertices["x0"].edges) == 1
+
+
 class TestChaosProperty:
     """Randomized kill schedules: every acked tx survives recovery and
     the surviving state matches the fault-free run of the same workload

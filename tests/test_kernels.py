@@ -230,6 +230,57 @@ class TestSegmentSum:
         np.testing.assert_array_equal(((p[2] + p[1]) + p[0]).view(np.int32),
                                       x.view(np.int32))
 
+    @pytest.mark.parametrize("chips", [2, 4])
+    @pytest.mark.parametrize("n,e,bn,be,hub,hi", [
+        (3000, 20000, 256, 1024, 1500, None),  # a hub, ragged N and E
+        (1900, 9000, 256, 1024, None, 1000),   # upper ranges get nothing
+        (5000, 700, 128, 128, 4999, None),     # more blocks than chunks
+        (600, 0, 128, 256, None, None)])
+    def test_split_covers_every_edge_and_vertex_once(self, chips, n, e, bn,
+                                                     be, hub, hi):
+        """The chips' ranges cover every vertex once, their slices every
+        CSC edge once (each edge lies in exactly one used tile of its own
+        block, on the chip that owns its dst), each slice is the
+        whole-graph chunks at their places, and the slots leave the
+        layout's room."""
+        from repro.kernels.segment_sum import ops
+        rng = np.random.default_rng(n + e + chips)
+        _, dst = _sorted_segments(rng, n, e, hub=hub, hub_share=0.3, hi=hi)
+        src = rng.integers(0, n, e).astype(np.int32)
+        sp = ops.split_layout(src, dst, n, chips, bn, be)
+        nl, slots = sp.n_local, sp.src.size // chips
+        # the fewest whole blocks per chip that hold every vertex
+        assert nl % bn == 0 and chips * nl >= n > chips * (nl - bn)
+        owner = np.repeat(np.arange(chips), nl)[:n]
+        np.testing.assert_array_equal(np.bincount(owner, minlength=chips)
+                                      .sum(), n)
+        np.testing.assert_array_equal(
+            sp.edges, np.bincount(owner[dst], minlength=chips))
+        t = sp.tile_block.size // chips
+        hits = np.zeros(e, np.int64)
+        for d in range(chips):
+            ld = sp.dst[d * slots:(d + 1) * slots]
+            real = ld != ops.PAD_DST
+            k = int(real.sum())
+            assert np.all(real[:k]) and np.all(np.diff(ld[:k]) >= 0)
+            # the slice starts on the whole-graph chunk of its range's
+            # first edge
+            a = int(np.searchsorted(dst, d * nl)) // be * be
+            np.testing.assert_array_equal(ld[:k] + d * nl, dst[a:a + k])
+            np.testing.assert_array_equal(sp.src[d * slots:d * slots + k],
+                                          src[a:a + k])
+            used = int(sp.n_used[d])
+            tb = sp.tile_block[d * t:d * t + used]
+            tc = sp.tile_chunk[d * t:d * t + used]
+            assert set(tb.tolist()) == set(range(nl // bn))
+            for b, c in zip(tb.tolist(), tc.tolist()):
+                pos = np.arange(c * be, min((c + 1) * be, k))
+                mine = pos[(ld[pos] >= 0) & (ld[pos] // bn == b)]
+                hits[a + mine] += 1
+        np.testing.assert_array_equal(hits, 1)
+        mean = -(-(-(-e // be)) // chips)
+        assert slots // be >= mean + max(1, -(-mean // 4))
+
     def test_layout_refuses_unsorted_dst(self):
         from repro.kernels.segment_sum import ops
         with pytest.raises(ValueError, match="non-decreasing"):

@@ -18,13 +18,14 @@ from jax.sharding import Mesh, NamedSharding, PartitionSpec as P, \
     SingleDeviceSharding
 
 from bench.harness.kernel_cost import mv_visibility_bytes
-from repro.core.analytics import pagerank_tiled
+from repro.core.analytics import pagerank_split, pagerank_tiled
 from repro.dist.columns import TABLES, visibility_launch
 from repro.kernels import backend
 from repro.kernels.mv_visibility.kernel import before_pallas, \
     visibility_pallas
 from repro.kernels.segment_mp.kernel import DEFAULT_BLOCK_E, \
     DEFAULT_BLOCK_N, segment_mp_pallas
+from repro.kernels.segment_sum import ops as seg_ops
 from repro.kernels.segment_sum.kernel import GATHER_MAX_N, table_gather, \
     tiled_segment_sum
 
@@ -163,3 +164,42 @@ def test_table_gather_compiles(one_chip, n):
     fn = lambda t, i: table_gather(t, i, interpret=False)
     compiled = jax.jit(fn).lower(table, idx).compile()
     assert "tpu_custom_call" in compiled.as_text()
+
+
+def test_split_pagerank_compiles_on_four_chips(topo, monkeypatch):
+    """``pagerank_split`` over the four chips of a described ``v5e:2x2``
+    at the four-chip analytics cell's shapes: both kernels on every chip,
+    an all-gather between iterations, and each chip's arguments a quarter
+    of the edges (with ``split_layout``'s room of a quarter of that) plus
+    the whole out-degree table."""
+    monkeypatch.setattr(backend, "platform", lambda: "tpu")
+    n, e, chips = ANALYTICS_N, ANALYTICS_E, 4
+    bn, be = seg_ops.DEFAULT_BLOCK_N, seg_ops.DEFAULT_BLOCK_E
+    mesh = Mesh(np.array(topo.devices[:chips]), ("data",))
+    # an evenly spread dst column gives the layout's slot count, which
+    # depends on E and the chip count alone while the chips stay within
+    # a quarter of the mean
+    dst = np.arange(e, dtype=np.int64) * n // e
+    lay = seg_ops.split_layout(np.zeros(e, np.int32), dst, n, chips)
+    slots = lay.src.size // chips
+    part = NamedSharding(mesh, P("data"))
+    spec = lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=part)
+    deg = jax.ShapeDtypeStruct((lay.n_local * chips,), jnp.float32,
+                               sharding=NamedSharding(mesh, P()))
+    compiled = jax.jit(lambda *a: pagerank_split(
+        *a, n, 20, 0.85, mesh=mesh, block_n=bn, block_e=be)).lower(
+        spec(lay.src), spec(lay.dst), deg, spec(lay.tile_block),
+        spec(lay.tile_chunk), spec(lay.n_used)).compile()
+    text = compiled.as_text()
+    launches = sorted(ln.strip().removeprefix("ROOT ").split(".", 1)[0]
+                      for ln in text.splitlines() if "tpu_custom_call" in ln)
+    assert launches == ["%table_gather", "%tiled_segment_sum"]
+    assert "all-gather" in text
+    # per chip: src and dst (int32) over its slots, its tile list, the
+    # whole degree table, n_used and damping, each buffer aligned
+    quarter = e // chips * 8
+    args = compiled.memory_analysis().argument_size_in_bytes
+    need = slots * 8 + lay.tile_block.size // chips * 8 \
+        + deg.shape[0] * 4 + 8
+    assert need <= args < need + 4096
+    assert quarter < slots * 8 <= quarter * 5 // 4 + 2 * be * 8
