@@ -8,7 +8,7 @@ arrays and runs traversal node programs as frontier message-passing
 (`lax.while_loop` + segment reductions) — the same scatter-gather regime
 as the assigned GNN architectures.  On the TPU the Pallas kernels
 `repro.kernels.mv_visibility` (snapshot visibility) and
-`repro.kernels.segment_sum` (PageRank's edge pass) serve it.
+`repro.kernels.segment_sum` (the edge pass of PageRank and of BFS) serve it.
 
 Columnar snapshot engine
 ------------------------
@@ -102,11 +102,12 @@ class GraphArrays:
     sim: Optional[object] = None
 
     # lazily-derived views: CSC orientation ((dst<<32|src) keys from the
-    # engine), CSR row starts, and the CSC split over ``mesh`` on its
-    # devices
+    # engine), CSR row starts, the CSC's tile layout on one device and
+    # the CSC split over ``mesh`` on its devices
     _csc_key: Optional[np.ndarray] = None
     _csc: Optional[tuple] = None
     _indptr: Optional[np.ndarray] = None
+    _tiles: Optional[tuple] = None
     _split: Optional[tuple] = None
 
     @property
@@ -1119,74 +1120,142 @@ def bfs_levels(edge_src: jnp.ndarray, edge_dst: jnp.ndarray,
     return dist
 
 
-@partial(jax.jit, static_argnames=("n_nodes", "max_iters", "mesh",
-                                   "n_local"))
-def bfs_split(src, dst, sources, n_nodes: int,
-              max_iters: Optional[int] = None, *, mesh,
-              n_local: int) -> jnp.ndarray:
-    """:func:`bfs_levels` split by destination range over ``mesh``'s
-    ``"data"`` devices, on the slices of
-    :func:`repro.kernels.segment_sum.ops.split_layout`: every level each
-    chip relaxes its own edges into its own ``n_local`` vertices, then an
-    all-gather of the levels and a ``psum`` of the chips' "changed" flags
-    exchange them.  The same levels as :func:`bfs_levels`."""
-    from jax.sharding import PartitionSpec as P
+def _level_sync(dist0, max_iters: int, reached) -> jnp.ndarray:
+    """Level-synchronous BFS from ``dist0`` (0 at the sources, INF
+    elsewhere).  At level ``i`` the frontier is ``dist == i`` as float32
+    0/1 and ``reached(frontier)`` gives, as bool over the whole table, the
+    vertices with an in-edge from it; those still at INF get ``i + 1``.
+    The loop ends after a level that adds nothing, or ``max_iters``.
+
+    These are :func:`bfs_levels`' levels after as many iterations: after
+    ``k`` either holds exactly the levels up to ``k`` and INF elsewhere,
+    and the min-relaxation's iteration ``k`` changes something exactly
+    where a vertex lies at level ``k + 1``."""
+    def cond(state):
+        _, i, grew = state
+        return jnp.logical_and(grew, i < max_iters)
+
+    def body(state):
+        dist, i, _ = state
+        new = reached((dist == i).astype(jnp.float32)) & (dist == INF)
+        return jnp.where(new, i + 1, dist), i + 1, jnp.any(new)
+
+    dist, _, _ = jax.lax.while_loop(cond, body, (dist0, jnp.int32(0),
+                                                 jnp.bool_(True)))
+    return dist
+
+
+def _edge_gather(table, idx, block_e: int):
+    """``table[idx]`` for the edge pass: the MXU ``table_gather`` where
+    the table is small enough, else XLA's gather."""
+    from repro.kernels.segment_sum import kernel
+
+    if table.shape[0] <= kernel.GATHER_MAX_N:
+        return kernel.table_gather(table, idx, block_e=block_e)
+    return table[idx]
+
+
+@partial(jax.jit, static_argnames=("n_nodes", "max_iters", "block_n",
+                                   "block_e"))
+def bfs_tiled(csc_src, csc_dst, tile_block, tile_chunk, n_used, sources,
+              n_nodes: int, max_iters: Optional[int] = None, *,
+              block_n: int, block_e: int) -> jnp.ndarray:
+    """:func:`bfs_levels` on :func:`pagerank_tiled`'s padded CSC and tile
+    list, level-synchronous (:func:`_level_sync`).  Per level the frontier
+    bits are gathered once per edge (``table_gather``: the values are 0
+    and 1, so exact) and summed per destination by ``tiled_segment_sum``
+    (exact integers: every in-degree is below 2**24); a vertex is reached
+    where its count is positive."""
+    from repro.kernels.segment_sum.kernel import tiled_segment_sum
 
     if max_iters is None:
         max_iters = n_nodes
 
-    def chip(src, dst, dist0):
-        # edges of the straddled chunks outside the range, and padding,
-        # relax nothing; clipping keeps the segment ids sorted
-        inside = (dst >= 0) & (dst < n_local)
-        seg = jnp.clip(dst, 0, n_local - 1)
-        base = jax.lax.axis_index("data") * n_local
+    def reached(front):
+        vals = _edge_gather(front, csc_src, block_e)
+        return tiled_segment_sum(vals, csc_dst, tile_block, tile_chunk,
+                                 n_used, n_nodes, block_n=block_n,
+                                 block_e=block_e) > 0
 
-        def cond(state):
-            _, i, changed = state
-            return jnp.logical_and(changed, i < max_iters)
+    with jax.named_scope("weaver.bfs"):
+        dist0 = jnp.full((n_nodes,), INF, dtype=jnp.int32)
+        return _level_sync(dist0.at[sources].set(0), max_iters, reached)
 
-        def body(state):
-            dist, i, _ = state
-            d_src = dist[src]
-            cand = jnp.where(inside & (d_src < INF), d_src + 1, INF)
-            relaxed = jax.ops.segment_min(cand, seg, num_segments=n_local,
-                                          indices_are_sorted=True)
-            mine = jax.lax.dynamic_slice(dist, (base,), (n_local,))
-            nd = jnp.minimum(mine, relaxed)
+
+@partial(jax.jit, static_argnames=("n_nodes", "max_iters", "mesh",
+                                   "n_local", "block_n", "block_e"))
+def bfs_split(src, dst, tile_block, tile_chunk, n_used, sources,
+              n_nodes: int, max_iters: Optional[int] = None, *, mesh,
+              n_local: int, block_n: int, block_e: int) -> jnp.ndarray:
+    """:func:`bfs_tiled` split by destination range over ``mesh``'s
+    ``"data"`` devices, on :func:`pagerank_split`'s slices and tile lists.
+    Every chip holds the whole level table.  Per level each chip gathers
+    the whole frontier over its own edge slots and sums it into its own
+    ``n_local`` vertices; one all-gather of the chips' "reached" bits
+    then lets every chip update its whole table, and decides the loop's
+    end the same way on every chip.  The same levels as
+    :func:`bfs_levels`."""
+    from jax.sharding import PartitionSpec as P
+
+    from repro.kernels.segment_sum.kernel import tiled_segment_sum
+
+    if max_iters is None:
+        max_iters = n_nodes
+
+    def chip(src, dst, tile_block, tile_chunk, n_used, dist0):
+        def reached(front):
+            vals = _edge_gather(front, src, block_e)
+            mine = tiled_segment_sum(vals, dst, tile_block, tile_chunk,
+                                     n_used, n_local, block_n=block_n,
+                                     block_e=block_e) > 0
             with jax.named_scope("weaver.exchange"):
-                changed = jax.lax.psum(
-                    jnp.any(nd != mine).astype(jnp.int32), "data") > 0
-                dist = jax.lax.all_gather(nd, "data", tiled=True)
-            return dist, i + 1, changed
+                return jax.lax.all_gather(mine, "data", tiled=True)
 
-        dist, _, _ = jax.lax.while_loop(cond, body, (dist0, jnp.int32(0),
-                                                     jnp.bool_(True)))
-        return dist
+        return _level_sync(dist0, max_iters, reached)
 
     with jax.named_scope("weaver.bfs"):
         dist0 = jnp.full((n_local * mesh.size,), INF, dtype=jnp.int32)
         dist0 = dist0.at[sources].set(0)
         dist = jax.shard_map(chip, mesh=mesh,
-                             in_specs=(P("data"), P("data"), P()),
-                             out_specs=P(), check_vma=False)(src, dst, dist0)
+                             in_specs=(P("data"),) * 5 + (P(),),
+                             out_specs=P(), check_vma=False)(
+            src, dst, tile_block, tile_chunk, n_used, dist0)
     return dist[:n_nodes]
+
+
+#: :func:`bfs_levels_ga` calls on one device, on the tiled kernel path
+#: and on the XLA path (the split path counts in ``split_calls``)
+bfs_stats = {"tiled_calls": 0, "xla_calls": 0}
 
 
 def bfs_levels_ga(ga: GraphArrays, sources,
                   max_iters: Optional[int] = None) -> jnp.ndarray:
-    """BFS over a columnar snapshot, exploiting its CSC sort order;
-    split by destination range (:func:`bfs_split`) where ``ga.mesh``
-    spans more than one device."""
+    """BFS over a columnar snapshot, in its CSC orientation.
+
+    On the TPU (or with ``FORCE_KERNEL``, interpreted) each level runs in
+    the ``segment_sum`` kernels (:func:`bfs_tiled`) on the snapshot's
+    tile layout, which PageRank shares; elsewhere in :func:`bfs_levels`
+    with sorted ids.  Where ``ga.mesh`` spans more than one device, split
+    by destination range over it (:func:`bfs_split`, the same kernels).
+    The level-synchronous frontier of the kernel paths gives exactly
+    the min-relaxation's levels (:func:`_level_sync`)."""
+    n, sources = ga.n_nodes, jnp.asarray(sources)
     mesh = _split_mesh(ga)
     if mesh is not None:
         lay, a = _split_arrays(ga, mesh)
-        return bfs_split(a["src"], a["dst"], jnp.asarray(sources),
-                         ga.n_nodes, max_iters, mesh=mesh,
-                         n_local=lay.n_local)
-    return bfs_levels(jnp.asarray(ga.csc_src), jnp.asarray(ga.csc_dst),
-                      ga.n_nodes, jnp.asarray(sources), max_iters,
-                      dst_sorted=True)
+        return bfs_split(a["src"], a["dst"], a["tile_block"],
+                         a["tile_chunk"], a["n_used"], sources, n,
+                         max_iters, mesh=mesh, n_local=lay.n_local,
+                         block_n=lay.block_n, block_e=lay.block_e)
+    if not (_use_kernel() and n):
+        bfs_stats["xla_calls"] += 1
+        return bfs_levels(jnp.asarray(ga.csc_src), jnp.asarray(ga.csc_dst),
+                          n, sources, max_iters, dst_sorted=True)
+    lay, a = _tile_arrays(ga)
+    bfs_stats["tiled_calls"] += 1
+    return bfs_tiled(a["src"], a["dst"], a["tile_block"], a["tile_chunk"],
+                     a["n_used"], sources, n, max_iters,
+                     block_n=lay.block_n, block_e=lay.block_e)
 
 
 def reachable(edge_src, edge_dst, n_nodes: int, source: int,
@@ -1263,14 +1332,10 @@ def pagerank_tiled(csc_src, csc_dst, deg, tile_block, tile_chunk, n_used,
     per edge (for each edge the value ``pr[src] / deg[src]`` is) by the
     MXU ``table_gather`` where the table is small enough, else by XLA,
     and summed per destination by the tiled ``segment_sum`` kernel."""
-    from repro.kernels.segment_sum.kernel import (GATHER_MAX_N,
-                                                  table_gather,
-                                                  tiled_segment_sum)
+    from repro.kernels.segment_sum.kernel import tiled_segment_sum
 
     def body(_, pr):
-        c = pr / deg
-        vals = (table_gather(c, csc_src, block_e=block_e)
-                if n_nodes <= GATHER_MAX_N else c[csc_src])
+        vals = _edge_gather(pr / deg, csc_src, block_e)
         agg = tiled_segment_sum(vals, csc_dst, tile_block, tile_chunk,
                                 n_used, n_nodes, block_n=block_n,
                                 block_e=block_e)
@@ -1297,18 +1362,14 @@ def pagerank_split(src, dst, deg, tile_block, tile_chunk, n_used,
     in the same order: bit-equal to :func:`pagerank_tiled`."""
     from jax.sharding import PartitionSpec as P
 
-    from repro.kernels.segment_sum.kernel import (GATHER_MAX_N,
-                                                  table_gather,
-                                                  tiled_segment_sum)
+    from repro.kernels.segment_sum.kernel import tiled_segment_sum
 
     n_pad = deg.shape[0]
     n_local = n_pad // mesh.size
 
     def chip(src, dst, tile_block, tile_chunk, n_used, deg, damping):
         def body(_, pr):
-            c = pr / deg
-            vals = (table_gather(c, src, block_e=block_e)
-                    if n_pad <= GATHER_MAX_N else c[src])
+            vals = _edge_gather(pr / deg, src, block_e)
             agg = tiled_segment_sum(vals, dst, tile_block, tile_chunk,
                                     n_used, n_local, block_n=block_n,
                                     block_e=block_e)
@@ -1333,6 +1394,28 @@ def _split_mesh(ga: GraphArrays):
     if mesh is None or mesh.size < 2 or not ga.n_nodes:
         return None
     return mesh
+
+
+def _tile_arrays(ga: GraphArrays):
+    """``ga``'s CSC tile layout
+    (:func:`repro.kernels.segment_sum.ops.tile_layout`) and the arguments
+    of :func:`pagerank_tiled` and :func:`bfs_tiled` on the device: the
+    CSC padded to whole chunks (src 0, dst -1), the tile list and the
+    floored out-degree, made once per snapshot and shared by both."""
+    if ga._tiles is None:
+        from repro.kernels.segment_sum import ops as seg
+
+        n, src, dst = ga.n_nodes, ga.csc_src, ga.csc_dst
+        lay = seg.tile_layout(dst, n)
+        deg = np.maximum(np.bincount(src, minlength=n), 1)
+        ga._tiles = (lay, {
+            "src": jnp.asarray(seg.pad_edges(src, lay, 0)),
+            "dst": jnp.asarray(seg.pad_edges(dst, lay, -1)),
+            "deg": jnp.asarray(deg.astype(np.float32)),
+            "tile_block": jnp.asarray(lay.tile_block),
+            "tile_chunk": jnp.asarray(lay.tile_chunk),
+            "n_used": jnp.asarray(lay.n_used)})
+    return ga._tiles
 
 
 def _split_arrays(ga: GraphArrays, mesh):
@@ -1383,8 +1466,10 @@ def pagerank_ga(ga: GraphArrays, n_iters: int = 20,
     """PageRank over a columnar snapshot, in its CSC orientation.
 
     On the TPU the edge pass runs in the ``segment_sum`` kernels
-    (:func:`pagerank_tiled`; tile list and out-degree built once per
-    call on the host); elsewhere in :func:`pagerank` with sorted ids.
+    (:func:`pagerank_tiled`, on the snapshot's tile layout and
+    out-degree, built on the host once per snapshot and shared with
+    :func:`bfs_levels_ga`); elsewhere in :func:`pagerank` with sorted
+    ids.
     Where ``ga.mesh`` spans more than one device, split by destination
     range over it (:func:`pagerank_split`, the same kernels, interpreted
     on the CPU)."""
@@ -1400,21 +1485,15 @@ def pagerank_ga(ga: GraphArrays, n_iters: int = 20,
         pagerank_stats["xla_calls"] += 1
         return pagerank(jnp.asarray(ga.csc_src), jnp.asarray(ga.csc_dst),
                         n, n_iters, damping, dst_sorted=True)
-    from repro.kernels.segment_sum import ops as seg
-
-    src, dst = ga.csc_src, ga.csc_dst
-    lay = seg.tile_layout(dst, n)
-    deg = np.maximum(np.bincount(src, minlength=n), 1).astype(np.float32)
+    lay, a = _tile_arrays(ga)
     pagerank_stats["tiled_calls"] += 1
     pagerank_stats["tiles"] += int(lay.n_used[0])
     pagerank_stats["edges"] += lay.n_edges
     pagerank_stats["masked_slots"] += lay.masked_slots
     return pagerank_tiled(
-        jnp.asarray(seg.pad_edges(src, lay, 0)),
-        jnp.asarray(seg.pad_edges(dst, lay, -1)), jnp.asarray(deg),
-        jnp.asarray(lay.tile_block), jnp.asarray(lay.tile_chunk),
-        jnp.asarray(lay.n_used), n, n_iters, damping,
-        block_n=lay.block_n, block_e=lay.block_e)
+        a["src"], a["dst"], a["deg"], a["tile_block"], a["tile_chunk"],
+        a["n_used"], n, n_iters, damping, block_n=lay.block_n,
+        block_e=lay.block_e)
 
 
 @partial(jax.jit, static_argnames=("n_nodes",))

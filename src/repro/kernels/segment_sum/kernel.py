@@ -22,6 +22,17 @@ The grid runs over the tiles in block order:
 
 Every value and every partial sum is float32: no bf16 pass anywhere.
 
+The two kernels make one edge pass of the graph kernels on the TPU
+(``repro.core.analytics``): a PageRank iteration gathers ``pr / deg``
+and sums it per destination (``pagerank_tiled``), and a BFS level
+gathers the frontier's bits (``dist == level`` as 0.0/1.0) and counts
+them per destination (``bfs_tiled``), a vertex being reached where its
+count is positive.  The bits gather exactly and the counts are exact
+integers in float32 (in-degrees below 2**24), so each level holds the
+same vertices as XLA's ``segment_min`` relaxation, which the CPU keeps.
+Split over several chips (``pagerank_split``, ``bfs_split``) each chip
+runs both kernels on its destination range.
+
 :func:`table_gather` is the edge pass's other half, ``table[idx]`` for a
 float32 table small enough for VMEM, as a two-level one-hot on the MXU.
 With ``idx = hi * 128 + lo``, the table is held as ``(128, B)``

@@ -7,11 +7,13 @@ chunks that range touches (one tile for a block with no edges, so its
 output row is written), and the list is padded to the fixed length
 ``T_max = ceil(E / BE) + ceil(N / BN)``, which bounds the chunks plus one
 extra per block boundary.  Compiled shapes then depend on E and N alone.
+A snapshot's layout is built once on one device and shared by PageRank
+and BFS (``repro.core.analytics.pagerank_tiled`` and ``bfs_tiled``).
 
 :func:`split_layout` cuts the same dst-sorted edges by destination range
 over several chips, one :func:`tile_layout` per chip, for the graph
 kernels split across chips (``repro.core.analytics.pagerank_split`` and
-``bfs_split``).
+``bfs_split``), also built once per snapshot.
 """
 
 from __future__ import annotations

@@ -21,6 +21,44 @@ def _random_edges(rng, n, m):
     return src, dst
 
 
+def _rmat_edges(rng, scale):
+    """R-MAT (0.57/0.19/0.19/0.05) over 2**scale permuted ids, edge factor
+    8, one direction."""
+    src = np.zeros(8 * (1 << scale), np.int64)
+    dst = np.zeros_like(src)
+    for _ in range(scale):
+        q = rng.choice(4, src.size, p=[0.57, 0.19, 0.19, 0.05])
+        src, dst = 2 * src + q // 2, 2 * dst + q % 2
+    perm = rng.permutation(1 << scale)
+    return perm[src], perm[dst]
+
+
+def _bfs_case(case):
+    """(src, dst, n, sources, max_iters) of a tiled-BFS test case."""
+    rng = np.random.default_rng(11)
+    if case in ("rmat", "sources", "capped"):
+        # 512 R-MAT ids, both directions, and 88 vertices with no edges
+        src, dst = _rmat_edges(rng, 9)
+        src, dst, n = np.r_[src, dst], np.r_[dst, src], 600
+        roots = {"rmat": [int(src[0])],
+                 "sources": [int(src[0]), int(dst[-1]), 599, int(src[0])],
+                 "capped": [int(src[0])]}[case]
+        return src, dst, n, roots, 2 if case == "capped" else None
+    if case == "random":
+        src, dst = _random_edges(rng, 700, 3000)
+        return src, dst, 700, [0], None
+    if case == "unreachable":
+        # edges among the first 200 vertices, 250 -> 0 (250 is never
+        # reached) and vertices 200..299 otherwise isolated
+        src, dst = _random_edges(rng, 200, 600)
+        return np.r_[src, 250], np.r_[dst, 0], 300, [0], None
+    if case == "empty":
+        return np.zeros(0, np.int64), np.zeros(0, np.int64), 40, [3], None
+    # "mid_chunk": two whole 1,024-slot chunks and one edge of a third
+    src, dst = _random_edges(rng, 500, 2 * 1024 + 1)
+    return src, dst, 500, [int(src[0])], None
+
+
 class TestFrontierPrograms:
     def test_bfs_levels_line_graph(self):
         src = np.array([0, 1, 2], dtype=np.int32)
@@ -91,14 +129,8 @@ class TestFrontierPrograms:
             jax.clear_caches()
             request.addfinalizer(jax.clear_caches)
         rng = np.random.default_rng(7)
-        scale, n = 9, 600                     # 512 R-MAT ids + 88 more
-        src = np.zeros(8 * (1 << scale), np.int64)
-        dst = np.zeros_like(src)
-        for _ in range(scale):
-            q = rng.choice(4, src.size, p=[0.57, 0.19, 0.19, 0.05])
-            src, dst = 2 * src + q // 2, 2 * dst + q % 2
-        perm = rng.permutation(1 << scale)
-        src, dst = perm[src], perm[dst]
+        n = 600                               # 512 R-MAT ids + 88 more
+        src, dst = _rmat_edges(rng, 9)
         src, dst = np.r_[src, dst, [0, 1, 2]], np.r_[dst, src, [597] * 3]
         ga = A.GraphArrays(vids=list(range(n)), index={},
                            edge_src=src.astype(np.int32),
@@ -122,6 +154,47 @@ class TestFrontierPrograms:
         np.testing.assert_allclose(np.asarray(A.pagerank_ga(ga)), want,
                                    rtol=1e-5)
         assert A.pagerank_stats["xla_calls"] == before["xla_calls"] + 1
+
+    @pytest.mark.parametrize("case", ["rmat", "random", "sources",
+                                      "unreachable", "empty", "mid_chunk",
+                                      "capped"])
+    def test_tiled_bfs_matches_plain_and_reference(self, monkeypatch, case):
+        """``bfs_levels_ga`` on the tiled kernel path (interpreted here):
+        the frontier-bit gather and segment sum give exactly the levels of
+        the min-relaxation ``bfs_levels`` and of the benchmark's numpy
+        reference (several sources: the least level over them; a
+        ``max_iters`` cap: INF past it), and the call is counted.
+        PageRank and BFS on one snapshot build its tile layout once."""
+        from bench.harness import reference
+        from repro.kernels.segment_sum import ops as seg
+
+        src, dst, n, roots, max_iters = _bfs_case(case)
+        o = np.lexsort((src, dst))
+        src, dst = src[o].astype(np.int32), dst[o].astype(np.int32)
+        ga = A.GraphArrays(vids=list(range(n)), index={}, edge_src=src,
+                           edge_dst=dst, n_nodes=n, _csc=(src, dst))
+        layouts = []
+        tile_layout = seg.tile_layout
+        monkeypatch.setattr(seg, "tile_layout",
+                            lambda *a: layouts.append(a) or tile_layout(*a))
+        monkeypatch.setattr(A, "FORCE_KERNEL", True)
+        before = dict(A.bfs_stats)
+        A.pagerank_ga(ga)
+        got = np.asarray(A.bfs_levels_ga(ga, roots, max_iters))
+        assert len(layouts) == 1
+        assert A.bfs_stats == {"tiled_calls": before["tiled_calls"] + 1,
+                               "xla_calls": before["xla_calls"]}
+        plain = A.bfs_levels(jnp.asarray(src), jnp.asarray(dst), n,
+                             jnp.asarray(roots), max_iters)
+        np.testing.assert_array_equal(got, np.asarray(plain))
+        lv = np.stack([reference.bfs_levels(src, dst, n, r) for r in roots])
+        want = np.where(lv < 0, A.INF, lv).min(axis=0)
+        if max_iters is not None:
+            assert (want > max_iters).any()
+            want = np.where(want > max_iters, A.INF, want)
+        np.testing.assert_array_equal(got, want)
+        if case == "unreachable":
+            assert (got[200:] == A.INF).all()
 
     def test_sssp_weighted(self):
         src = np.array([0, 0, 1], dtype=np.int32)

@@ -18,7 +18,8 @@ from jax.sharding import Mesh, NamedSharding, PartitionSpec as P, \
     SingleDeviceSharding
 
 from bench.harness.kernel_cost import mv_visibility_bytes
-from repro.core.analytics import pagerank_split, pagerank_tiled
+from repro.core.analytics import bfs_split, bfs_tiled, pagerank_split, \
+    pagerank_tiled
 from repro.dist.columns import TABLES, visibility_launch
 from repro.kernels import backend
 from repro.kernels.mv_visibility.kernel import before_pallas, \
@@ -117,12 +118,13 @@ def test_column_plane_launch_compiles_on_four_chips(topo):
 
 
 @pytest.mark.parametrize("block_n,block_e", [(256, 1024), (128, 2048)])
-@pytest.mark.parametrize("what", ["kernel", "pagerank"])
+@pytest.mark.parametrize("what", ["kernel", "pagerank", "bfs"])
 def test_segment_sum_compiles_at_analytics_shapes(one_chip, monkeypatch,
                                                   what, block_n, block_e):
-    """The tiled segment sum alone and inside ``pagerank_tiled`` at the
-    analytics cell's shapes; its launch is not an ``mv_visibility`` one,
-    so ``mv_visibility_roofline`` keeps reading only those."""
+    """The tiled segment sum alone and inside ``pagerank_tiled`` and
+    ``bfs_tiled`` at the analytics cell's shapes; its launch is not an
+    ``mv_visibility`` one, so ``mv_visibility_roofline`` keeps reading
+    only those."""
     # the process runs on the CPU: make the kernel's interpret=None
     # resolve as it does on the chip
     monkeypatch.setattr(backend, "platform", lambda: "tpu")
@@ -133,23 +135,30 @@ def test_segment_sum_compiles_at_analytics_shapes(one_chip, monkeypatch,
                                                   sharding=one_chip)
     tiles = (spec((t_max,), jnp.int32), spec((t_max,), jnp.int32),
              spec((1,), jnp.int32))
+    after = ()
     if what == "kernel":
         fn = lambda v, d, tb, tc, nu: tiled_segment_sum(
             v, d, tb, tc, nu, n, block_n=block_n, block_e=block_e,
             interpret=False)
         args = (spec((e_pad,), jnp.float32), spec((e_pad,), jnp.int32))
-    else:
+    elif what == "pagerank":
         fn = lambda s, d, deg, tb, tc, nu: pagerank_tiled(
             s, d, deg, tb, tc, nu, n, 20, 0.85, block_n=block_n,
             block_e=block_e)
         args = (spec((e_pad,), jnp.int32), spec((e_pad,), jnp.int32),
                 spec((n,), jnp.float32))
-    compiled = jax.jit(fn).lower(*args, *tiles).compile()
+    else:
+        fn = lambda s, d, tb, tc, nu, roots: bfs_tiled(
+            s, d, tb, tc, nu, roots, n, block_n=block_n, block_e=block_e)
+        args = (spec((e_pad,), jnp.int32), spec((e_pad,), jnp.int32))
+        after = (spec((1,), jnp.int32),)
+    compiled = jax.jit(fn).lower(*args, *tiles, *after).compile()
     launches = [ln.strip().removeprefix("ROOT ")
                 for ln in compiled.as_text().splitlines()
                 if "tpu_custom_call" in ln]
     names = {"kernel": ["%tiled_segment_sum"],
-             "pagerank": ["%table_gather", "%tiled_segment_sum"]}[what]
+             "pagerank": ["%table_gather", "%tiled_segment_sum"],
+             "bfs": ["%table_gather", "%tiled_segment_sum"]}[what]
     assert sorted(ln.split(".", 1)[0] for ln in launches) == names
     assert all(mv_visibility_bytes(ln) is None for ln in launches)
 
@@ -203,3 +212,30 @@ def test_split_pagerank_compiles_on_four_chips(topo, monkeypatch):
         + deg.shape[0] * 4 + 8
     assert need <= args < need + 4096
     assert quarter < slots * 8 <= quarter * 5 // 4 + 2 * be * 8
+
+
+def test_split_bfs_compiles_on_four_chips(topo, monkeypatch):
+    """``bfs_split`` over the four chips of a described ``v5e:2x2`` at
+    the four-chip analytics cell's shapes: both kernels on every chip and
+    an all-gather of the reached bits between levels, which also decides
+    the loop's end, so no all-reduce is left."""
+    monkeypatch.setattr(backend, "platform", lambda: "tpu")
+    n, e, chips = ANALYTICS_N, ANALYTICS_E, 4
+    mesh = Mesh(np.array(topo.devices[:chips]), ("data",))
+    dst = np.arange(e, dtype=np.int64) * n // e
+    lay = seg_ops.split_layout(np.zeros(e, np.int32), dst, n, chips)
+    part = NamedSharding(mesh, P("data"))
+    spec = lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=part)
+    roots = jax.ShapeDtypeStruct((1,), jnp.int32,
+                                 sharding=NamedSharding(mesh, P()))
+    compiled = jax.jit(lambda *a: bfs_split(
+        *a, n, mesh=mesh, n_local=lay.n_local, block_n=lay.block_n,
+        block_e=lay.block_e)).lower(
+        spec(lay.src), spec(lay.dst), spec(lay.tile_block),
+        spec(lay.tile_chunk), spec(lay.n_used), roots).compile()
+    text = compiled.as_text()
+    launches = sorted(ln.strip().removeprefix("ROOT ").split(".", 1)[0]
+                      for ln in text.splitlines() if "tpu_custom_call" in ln)
+    assert launches == ["%table_gather", "%tiled_segment_sum"]
+    assert "all-gather" in text
+    assert "all-reduce" not in text
