@@ -21,7 +21,6 @@ Prints ``name,metric,value`` CSV lines (simulated time; deterministic).
                    equivalence + modeled device scaling; columnar BSP
                    vs interpreted; Weaver vs columnar BSP (Fig. 11
                    at the columnar baseline)
-  roofline       — §Roofline summary from the dry-run artifacts
 
 A benchmark that raises is reported, the remaining modules still run,
 and the harness exits non-zero at the end — failures are loud, never
@@ -56,8 +55,8 @@ def main(argv=None) -> None:
     backend.use_compile_cache()
 
     from . import (block_query, coordination, nodeprog, recovery,
-                   replication, roofline, scalability, scaling, serving,
-                   snapshot, social, traversal, writepath)
+                   replication, scalability, scaling, serving, snapshot,
+                   social, traversal, writepath)
 
     modules = [("snapshot", snapshot), ("nodeprog", nodeprog),
                ("writepath", writepath), ("recovery", recovery),
@@ -65,8 +64,7 @@ def main(argv=None) -> None:
                ("serving", serving), ("block_query", block_query),
                ("social", social), ("traversal", traversal),
                ("scalability", scalability),
-               ("coordination", coordination), ("scaling", scaling),
-               ("roofline", roofline)]
+               ("coordination", coordination), ("scaling", scaling)]
     if smoke:
         modules = [("snapshot", snapshot), ("nodeprog", nodeprog),
                    ("writepath", writepath), ("recovery", recovery),

@@ -30,10 +30,10 @@ echo "=== tier-1 pytest ==="
 python -m pytest -x -q
 
 echo "=== multi-device (8 forced host devices) ==="
-# re-runs the tests that self-skip under a single device: collective
-# costing inside scans and the device-sharded column-plane equivalence
+# re-runs the tests that self-skip under a single device: the
+# device-sharded column-plane equivalence
 XLA_FLAGS="--xla_force_host_platform_device_count=8" \
-    python -m pytest -x -q tests/test_hlo_cost.py tests/test_device_shard.py
+    python -m pytest -x -q tests/test_device_shard.py
 
 echo "=== benchmarks (smoke) ==="
 python -m benchmarks.run --smoke

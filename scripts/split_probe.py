@@ -18,8 +18,9 @@ JSON line, also written to ``OUT_DIR/split_probe.json``:
 * ``devices``: from a profiler trace of 3 calls of each split kernel, per
   device, device ms per call of the operations under the name scopes
   ``weaver.pagerank``, ``weaver.bfs`` and ``weaver.exchange`` (read from
-  each operation's ``tf_op`` stat) and of the collective operations,
-  with the collectives' names and the stat keys the operations carry.
+  each operation's ``tf_op`` stat) and of the collective operations
+  (``collective_ms``), with their names and the stat keys the operations
+  carry.
 """
 
 from __future__ import annotations
@@ -58,8 +59,8 @@ def _median_ms(fn, reps=5):
 
 def _per_device(fns, calls=3):
     """Device ms per call, per device, of the name scopes and of the
-    collectives, from a profiler trace of ``calls`` calls of each of
-    ``fns``."""
+    collective operations, from a profiler trace of ``calls`` calls of
+    each of ``fns``."""
     import jax
 
     from bench.harness import trace as btrace
@@ -75,7 +76,7 @@ def _per_device(fns, calls=3):
         for pl in pd.planes:
             if not re.match(r"^/device:TPU:\d+$", pl.name):
                 continue
-            row = {k: 0.0 for k in SCOPES + ("collectives",)}
+            row = {k: 0.0 for k in SCOPES + ("collective_ms",)}
             names, keys = set(), set()
             for ln in pl.lines:
                 if ln.name != "XLA Ops":
@@ -90,7 +91,7 @@ def _per_device(fns, calls=3):
                             row[k] += ms
                     name = btrace.short_name(e.name)
                     if COLLECTIVE.match(name):
-                        row["collectives"] += ms
+                        row["collective_ms"] += ms
                         names.add(re.sub(r"\.\d+$", "", name))
             row["collective_ops"] = sorted(names)
             row["stat_keys"] = sorted(keys)

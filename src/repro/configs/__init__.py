@@ -1,23 +1,18 @@
-"""Architecture registry: ``--arch <id>`` resolution for the launcher.
-
-Ten assigned architectures (5 LM, 4 GNN, 1 recsys) + the paper system's
-own deployment config.  Each ArchSpec carries its own shape set, so every
-(arch x shape) cell of the 40-cell dry-run grid is well-defined.
+"""Architecture registry (the four GNNs of the dynamic-graph training
+path, looked up by ``get_arch(<id>)``) and the paper system's own
+deployment config.
 """
 
 from __future__ import annotations
 
-from typing import Dict, List
+from typing import Dict
 
 from .base import ArchSpec, ShapeSpec
 
-from . import (chatglm3_6b, dimenet, gat_cora, gemma3_1b, gin_tu,
-               moonshot_v1_16b_a3b, phi4_mini_3p8b, pna, qwen3_moe_235b_a22b,
-               sasrec)
+from . import dimenet, gat_cora, gin_tu, pna
 from .weaver_store import PAPER_DEPLOYMENT
 
-_MODULES = [moonshot_v1_16b_a3b, qwen3_moe_235b_a22b, phi4_mini_3p8b,
-            gemma3_1b, chatglm3_6b, gin_tu, pna, dimenet, gat_cora, sasrec]
+_MODULES = [gin_tu, pna, dimenet, gat_cora]
 
 ARCHS: Dict[str, ArchSpec] = {m.SPEC.arch_id: m.SPEC for m in _MODULES}
 
@@ -28,15 +23,4 @@ def get_arch(arch_id: str) -> ArchSpec:
     return ARCHS[arch_id]
 
 
-def all_cells(include_skipped: bool = True) -> List[tuple]:
-    """Every (arch_id, shape_name, ShapeSpec) cell of the grid."""
-    out = []
-    for aid, spec in ARCHS.items():
-        for sname, sh in spec.shapes.items():
-            if include_skipped or not sh.skip:
-                out.append((aid, sname, sh))
-    return out
-
-
-__all__ = ["ARCHS", "get_arch", "all_cells", "ArchSpec", "ShapeSpec",
-           "PAPER_DEPLOYMENT"]
+__all__ = ["ARCHS", "get_arch", "ArchSpec", "ShapeSpec", "PAPER_DEPLOYMENT"]

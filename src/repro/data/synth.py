@@ -1,38 +1,11 @@
-"""Synthetic data generators: token streams, recsys interactions, and the
-social-network / blockchain graphs used by the paper's benchmarks."""
+"""Synthetic data generators: the social-network / blockchain graphs used
+by the paper's benchmarks."""
 
 from __future__ import annotations
 
-from typing import Iterator, List, Optional, Tuple
+from typing import List, Tuple
 
 import numpy as np
-
-
-def token_batches(rng: np.random.Generator, vocab: int, batch: int,
-                  seq: int) -> Iterator[dict]:
-    """Zipfian token stream with next-token labels."""
-    ranks = np.arange(1, vocab + 1)
-    probs = 1.0 / ranks
-    probs /= probs.sum()
-    while True:
-        toks = rng.choice(vocab, size=(batch, seq + 1), p=probs)
-        yield {"tokens": toks[:, :-1].astype(np.int32),
-               "labels": toks[:, 1:].astype(np.int32)}
-
-
-def lm_batch(rng: np.random.Generator, vocab: int, batch: int,
-             seq: int) -> dict:
-    toks = rng.integers(0, vocab, size=(batch, seq + 1))
-    return {"tokens": toks[:, :-1].astype(np.int32),
-            "labels": toks[:, 1:].astype(np.int32)}
-
-
-def sasrec_batch(rng: np.random.Generator, n_items: int, batch: int,
-                 seq: int) -> dict:
-    hist = rng.integers(1, n_items + 1, size=(batch, seq)).astype(np.int32)
-    pos = rng.integers(1, n_items + 1, size=(batch, seq)).astype(np.int32)
-    neg = rng.integers(1, n_items + 1, size=(batch, seq)).astype(np.int32)
-    return {"hist": hist, "pos": pos, "neg": neg}
 
 
 # ---------------------------------------------------------------- paper data

@@ -1,20 +1,17 @@
-"""Distributed utilities: mesh registry + sharding-constraint helpers.
+"""Distributed utilities: the device column plane (:mod:`.columns`) and
+the process-global mesh registry the GNN models consult.
 
-The models reference a process-global mesh so the same forward functions
-run unmodified on a single CPU device (mesh unset -> every helper is an
-identity / trivial answer) and under `jax.jit` on a production mesh
-(`launch.dryrun` calls :func:`set_mesh` before lowering).
-
-Spec arguments to :func:`constrain` are FUNCTIONS of the mesh (e.g.
-``lambda m: P(dp_axes(m), "model")``) so model code never has to know
-which axes exist in the current deployment.
+The GNN forward functions call :func:`constrain`, which is an identity
+while no mesh is installed (single device) and a sharding constraint
+under a mesh installed with :func:`set_mesh`.  Spec arguments to
+:func:`constrain` are FUNCTIONS of the mesh (e.g.
+``lambda m: P(tuple(m.axis_names), None)``) so model code never has to
+know which axes exist in the current deployment.
 """
 
 from __future__ import annotations
 
-from typing import Callable, Optional, Sequence, Union
-
-from . import collectives, sharding
+from typing import Callable
 
 _MESH = None
 
@@ -23,23 +20,6 @@ def set_mesh(mesh) -> None:
     """Install the process-global mesh (None to clear)."""
     global _MESH
     _MESH = mesh
-
-
-def get_mesh():
-    """The installed mesh, or None (single-device / smoke-test mode)."""
-    return _MESH
-
-
-def axis_size(mesh, axes: Union[None, str, Sequence[str]]) -> int:
-    """Product of the named mesh axis sizes (1 when unset/empty)."""
-    if mesh is None or axes is None:
-        return 1
-    if isinstance(axes, str):
-        axes = (axes,)
-    size = 1
-    for a in axes:
-        size *= int(mesh.shape[a])
-    return size
 
 
 def constrain(x, spec_fn: Callable):
@@ -53,4 +33,3 @@ def constrain(x, spec_fn: Callable):
 
     return jax.lax.with_sharding_constraint(
         x, NamedSharding(mesh, spec_fn(mesh)))
-

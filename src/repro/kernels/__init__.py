@@ -1,4 +1,4 @@
-"""Pallas TPU kernels for the framework's compute hot-spots.
+"""Pallas TPU kernels for the database's compute hot-spots.
 
 Each kernel package has: kernel.py (pl.pallas_call + BlockSpec),
 ops.py (jit'd public wrapper, layout prep), ref.py (pure-jnp oracle).
@@ -14,8 +14,4 @@ XLA operations, against float64 numpy and XLA's own gather instead.
                     (exact MXU gather from a VMEM-resident table) and
                     ``tiled_segment_sum`` (float32 dst-tiled segment sum
                     over CSC edges, replacing the sorted scatter-add)
-  flash_attention — blocked online-softmax attention (causal + sliding
-                    window), the LM prefill hot-spot
-  embedding_bag   — BlockSpec-driven dynamic row gather + bag reduce
-                    (recsys embedding lookup)
 """

@@ -1,36 +1,10 @@
-"""Production mesh factory.
+"""Mesh factory for the device-sharded column plane.
 
 Defined as a FUNCTION so importing this module never touches jax device
-state.  Single pod: (data=16, model=16) = 256 chips (TPU v5e pod).
-Multi-pod: (pod=2, data=16, model=16) = 512 chips; the 'pod' axis is pure
-data parallelism whose gradient all-reduce crosses DCN once per step.
+state.
 """
 
 from __future__ import annotations
-
-import numpy as np
-
-
-def make_production_mesh(*, multi_pod: bool = False):
-    import jax
-
-    shape = (2, 16, 16) if multi_pod else (16, 16)
-    axes = ("pod", "data", "model") if multi_pod else ("data", "model")
-    n = int(np.prod(shape))
-    devices = jax.devices()
-    if len(devices) < n:
-        raise RuntimeError(
-            f"mesh {shape} needs {n} devices but only {len(devices)} "
-            f"present — run under XLA_FLAGS=--xla_force_host_platform_"
-            f"device_count=512 (see launch/dryrun.py)")
-    return jax.make_mesh(shape, axes, devices=devices[:n])
-
-
-def make_smoke_mesh(shape=(1, 1), axes=("data", "model")):
-    """1-device mesh for CPU smoke tests of the sharded step functions."""
-    import jax
-
-    return jax.make_mesh(shape, axes, devices=jax.devices()[:1])
 
 
 def make_columns_mesh(n_devices=None):

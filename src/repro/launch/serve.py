@@ -1,8 +1,7 @@
-"""Serving launcher: ``python -m repro.launch.serve --mode <graph|lm|rec>``.
+"""Serving launcher: ``python -m repro.launch.serve``.
 
-graph: boot a Weaver deployment, load a synthetic social graph, serve the
+Boots a Weaver deployment, loads a synthetic social graph and serves the
 TAO read/write mix (the paper's native serving workload).
-lm / rec: batched model serving on reduced configs (CPU container).
 """
 
 from __future__ import annotations
@@ -64,54 +63,13 @@ def serve_graph(args) -> int:
     return 0
 
 
-def serve_lm(args) -> int:
-    import jax
-    from repro.configs import get_arch
-    from repro.launch.train import reduced_config
-    from repro.models import transformer
-    from repro.runtime import LMServer
-
-    spec = get_arch(args.arch or "gemma3-1b")
-    cfg = reduced_config(spec)
-    params = transformer.init_params(jax.random.PRNGKey(0), cfg)
-    rng = np.random.default_rng(args.seed)
-    srv = LMServer(params, cfg, batch=args.batch, max_len=64)
-    prompts = rng.integers(0, cfg.vocab, (args.batch, 16))
-    first = srv.prefill_batch(prompts)
-    toks = srv.decode(first, steps=16)
-    print(f"decoded {toks.shape} tokens for {args.batch} sessions")
-    return 0
-
-
-def serve_rec(args) -> int:
-    import jax
-    from repro.configs import get_arch
-    from repro.launch.train import reduced_config
-    from repro.models import sasrec
-    from repro.runtime import RecServer
-
-    cfg = reduced_config(get_arch("sasrec"))
-    params = sasrec.init_params(jax.random.PRNGKey(0), cfg)
-    rng = np.random.default_rng(args.seed)
-    srv = RecServer(params, cfg)
-    hist = rng.integers(1, cfg.n_items + 1, (args.batch, cfg.seq_len))
-    top = srv.top_k(hist, k=10)
-    print(f"top-10 recommendations for {args.batch} users: {top.shape}")
-    return 0
-
-
 def main(argv=None):
     ap = argparse.ArgumentParser()
-    ap.add_argument("--mode", choices=["graph", "lm", "rec"],
-                    default="graph")
-    ap.add_argument("--arch", default=None)
     ap.add_argument("--users", type=int, default=200)
     ap.add_argument("--requests", type=int, default=500)
-    ap.add_argument("--batch", type=int, default=8)
     ap.add_argument("--seed", type=int, default=0)
     args = ap.parse_args(argv)
-    return {"graph": serve_graph, "lm": serve_lm,
-            "rec": serve_rec}[args.mode](args)
+    return serve_graph(args)
 
 
 if __name__ == "__main__":

@@ -1,5 +1,5 @@
-"""Model zoo: decoder LMs (dense/MoE), GNNs, SASRec — pure-functional JAX."""
+"""GNN models (pure-functional JAX) and their message-passing seam."""
 
-from . import gnn, layers, moe, mp, sasrec, transformer
+from . import gnn, mp
 
-__all__ = ["gnn", "layers", "moe", "mp", "sasrec", "transformer"]
+__all__ = ["gnn", "mp"]

@@ -1,5 +1,4 @@
-from . import adamw, compress
+from . import adamw
 from .adamw import AdamWConfig, AdamWState, make_train_step
 
-__all__ = ["adamw", "compress", "AdamWConfig", "AdamWState",
-           "make_train_step"]
+__all__ = ["adamw", "AdamWConfig", "AdamWState", "make_train_step"]

@@ -1,5 +1,3 @@
-from .server import GraphQueryServer, LMServer, RecServer
-from .trainer import HeartbeatMonitor, Trainer, TrainerConfig
+from .server import GraphQueryServer
 
-__all__ = ["GraphQueryServer", "LMServer", "RecServer", "HeartbeatMonitor",
-           "Trainer", "TrainerConfig"]
+__all__ = ["GraphQueryServer"]

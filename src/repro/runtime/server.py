@@ -1,23 +1,13 @@
-"""Batched serving loops.
-
-* :class:`GraphQueryServer` — the paper-native server: batched node
-  programs against the Weaver store (the end-to-end serving driver of
-  examples/social_serve.py runs this under the TAO workload).
-* :class:`LMServer` — LM decode serving with a continuous batch of
-  sessions over a shared KV cache (prefill + decode_step).
-* :class:`RecServer` — SASRec scoring (catalog or candidate mode).
+"""Batched serving loop: :class:`GraphQueryServer` serves node programs
+and transactions against a Weaver deployment (the end-to-end serving
+driver of examples/social_serve.py runs it under the TAO workload).
 """
 
 from __future__ import annotations
 
-import dataclasses
 from typing import Callable, Dict, List, Optional, Tuple
 
-import jax
-import jax.numpy as jnp
 import numpy as np
-
-from repro.models import sasrec, transformer
 
 
 class GraphQueryServer:
@@ -208,59 +198,3 @@ class GraphQueryServer:
                 "goodput_per_s": state["ok"] / dur,
                 "latencies_s": lat}
 
-
-@dataclasses.dataclass
-class LMSession:
-    sid: int
-    prompt: np.ndarray
-    generated: List[int] = dataclasses.field(default_factory=list)
-
-
-class LMServer:
-    """Continuous-batch decode server (greedy sampling)."""
-
-    def __init__(self, params, cfg: transformer.LMConfig, batch: int,
-                 max_len: int):
-        self.params = params
-        self.cfg = cfg
-        self.batch = batch
-        self.max_len = max_len
-        self.cache = transformer.init_cache(cfg, batch, max_len)
-        self._prefill = jax.jit(
-            lambda p, t: transformer.prefill(p, t, cfg,
-                                             max_len=max_len))
-        self._decode = jax.jit(
-            lambda p, c, t: transformer.decode_step(p, c, t, cfg))
-
-    def prefill_batch(self, prompts: np.ndarray):
-        logits, self.cache = self._prefill(self.params,
-                                           jnp.asarray(prompts))
-        return np.asarray(jnp.argmax(logits[:, -1, :], axis=-1))
-
-    def decode(self, tokens: np.ndarray, steps: int) -> np.ndarray:
-        out = []
-        cur = jnp.asarray(tokens)[:, None]
-        for _ in range(steps):
-            logits, self.cache = self._decode(self.params, self.cache, cur)
-            cur = jnp.argmax(logits[:, -1, :], axis=-1)[:, None]
-            out.append(np.asarray(cur[:, 0]))
-        return np.stack(out, axis=1)
-
-
-class RecServer:
-    def __init__(self, params, cfg: sasrec.SASRecConfig):
-        self.params = params
-        self.cfg = cfg
-        self._catalog = jax.jit(
-            lambda p, h: sasrec.score_catalog(p, h, cfg))
-        self._cands = jax.jit(
-            lambda p, h, c: sasrec.score_candidates(p, h, c, cfg))
-
-    def top_k(self, hist: np.ndarray, k: int = 10) -> np.ndarray:
-        scores = self._catalog(self.params, jnp.asarray(hist))
-        _, idx = jax.lax.top_k(scores, k)
-        return np.asarray(idx)
-
-    def score(self, hist: np.ndarray, candidates: np.ndarray) -> np.ndarray:
-        return np.asarray(self._cands(self.params, jnp.asarray(hist),
-                                      jnp.asarray(candidates)))

@@ -1,4 +1,6 @@
 """Tests for the JAX analytics bridge (snapshot -> arrays -> traversals)."""
+import heapq
+
 import numpy as np
 import pytest
 import jax.numpy as jnp
@@ -57,6 +59,116 @@ def _bfs_case(case):
     # "mid_chunk": two whole 1,024-slot chunks and one edge of a third
     src, dst = _random_edges(rng, 500, 2 * 1024 + 1)
     return src, dst, 500, [int(src[0])], None
+
+
+GRAPH_SHAPES = ["empty", "isolated", "self_loops", "duplicates",
+                "two_components", "rmat"]
+
+
+def _graph_case(shape):
+    """(src, dst, n) of a library-kernel test graph."""
+    rng = np.random.default_rng(5)
+    if shape == "empty":
+        return np.zeros(0, np.int64), np.zeros(0, np.int64), 12
+    if shape == "isolated":
+        # edges among the first 30 vertices; 30..49 have none
+        src, dst = _random_edges(rng, 30, 60)
+        return src, dst, 50
+    if shape == "self_loops":
+        # 15 self-loops over 40 vertices; 39's only edge is its loop
+        src, dst = _random_edges(rng, 39, 80)
+        loops = np.r_[rng.integers(0, 39, 14), 39]
+        return np.r_[src, loops], np.r_[dst, loops], 40
+    if shape == "duplicates":
+        src, dst = _random_edges(rng, 40, 50)
+        return np.r_[src, src, src[:10]], np.r_[dst, dst, dst[:10]], 40
+    if shape == "two_components":
+        a_src, a_dst = _random_edges(rng, 25, 80)
+        b_src, b_dst = _random_edges(rng, 25, 80)
+        return np.r_[a_src, b_src + 25], np.r_[a_dst, b_dst + 25], 50
+    # Graphalytics' Graph500 R-MAT at scale 8, edge factor 8, both
+    # directions (the draw of test_device_shard's ``rmat_csc``)
+    src, dst = _rmat_edges(rng, 8)
+    return np.r_[src, dst], np.r_[dst, src], 256
+
+
+def _components_ref(src, dst, n):
+    """Union-find over the undirected edges: each vertex's label is the
+    least vertex id of its component."""
+    parent = list(range(n))
+
+    def find(v):
+        while parent[v] != v:
+            parent[v] = parent[parent[v]]
+            v = parent[v]
+        return v
+
+    for u, v in zip(src.tolist(), dst.tolist()):
+        ru, rv = find(u), find(v)
+        if ru != rv:
+            parent[max(ru, rv)] = min(ru, rv)
+    return np.array([find(v) for v in range(n)])
+
+
+def _dijkstra_ref(src, dst, w, n, source):
+    """float64 Dijkstra over the directed weighted edges; inf where
+    unreachable."""
+    adj = [[] for _ in range(n)]
+    for u, v, x in zip(src.tolist(), dst.tolist(), w.tolist()):
+        adj[u].append((v, x))
+    dist = np.full(n, np.inf)
+    dist[source] = 0.0
+    heap = [(0.0, source)]
+    while heap:
+        d, u = heapq.heappop(heap)
+        if d > dist[u]:
+            continue
+        for v, x in adj[u]:
+            if d + x < dist[v]:
+                dist[v] = d + x
+                heapq.heappush(heap, (d + x, v))
+    return dist
+
+
+class TestLibraryKernels:
+    """The library graph kernels no served request runs, against plain
+    references on graphs with no edges, isolated vertices, self-loops,
+    duplicate edges, two components and an R-MAT draw."""
+
+    @pytest.mark.parametrize("shape", GRAPH_SHAPES)
+    def test_connected_components_ga_matches_union_find(self, shape):
+        """Exactly the least vertex id of each weak component, through
+        the snapshot entry point (CSR order, src-sorted claim)."""
+        src, dst, n = _graph_case(shape)
+        o = np.lexsort((dst, src))
+        ga = A.GraphArrays(vids=list(range(n)), index={},
+                           edge_src=src[o].astype(np.int32),
+                           edge_dst=dst[o].astype(np.int32), n_nodes=n)
+        got = np.asarray(A.connected_components_ga(ga))
+        want = _components_ref(src, dst, n)
+        np.testing.assert_array_equal(got, want)
+        if shape == "two_components":
+            assert set(got.tolist()) == {0, 25}
+
+    @pytest.mark.parametrize("shape", GRAPH_SHAPES)
+    def test_sssp_weighted_matches_dijkstra(self, shape):
+        """Within float32 rounding of a float64 Dijkstra from vertex 0
+        (a path of k edges: k float32 additions, each within 2**-24 of
+        its sum); the float32 sentinel exactly where nothing reaches."""
+        src, dst, n = _graph_case(shape)
+        rng = np.random.default_rng(n)
+        w = rng.uniform(0.5, 4.0, src.size).astype(np.float32)
+        got = np.asarray(A.sssp_weighted(
+            jnp.asarray(src.astype(np.int32)),
+            jnp.asarray(dst.astype(np.int32)), jnp.asarray(w), n,
+            jnp.asarray([0])))
+        want = _dijkstra_ref(src, dst, w.astype(np.float64), n, 0)
+        reach = np.isfinite(want)
+        np.testing.assert_array_equal(got < np.float32(3.4e38), reach)
+        np.testing.assert_array_equal(got[~reach], np.float32(3.4e38))
+        np.testing.assert_allclose(got[reach], want[reach], rtol=1e-6)
+        if shape == "two_components":
+            assert not reach[25:].any()
 
 
 class TestFrontierPrograms:

@@ -1,4 +1,6 @@
 """Unit + property tests for refinable timestamps and the timeline oracle."""
+import jax
+import jax.numpy as jnp
 import numpy as np
 import pytest
 
@@ -7,9 +9,12 @@ hypothesis = pytest.importorskip(
     "(pip install -r requirements-dev.txt)")
 from hypothesis import given, settings, strategies as st
 
+from repro.core import clock, writepath
 from repro.core.clock import (Order, Stamp, compare, merge, pack, pack_many,
                               visibility_mask_np, zero)
 from repro.core.oracle import (KIND_PROG, KIND_TX, CycleError, TimelineOracle)
+from repro.kernels.mv_visibility import ops as mv_ops
+from repro.kernels.mv_visibility.ref import before_cm
 
 
 def S(epoch, clock, gk=0):
@@ -74,6 +79,116 @@ class TestVectorClock:
             if deletes[i] is not None and compare(deletes[i], q) is Order.BEFORE:
                 vis = False
             assert bool(mask[i]) == vis
+
+
+def _rows_around(rng, g):
+    """A query stamp (epoch 1) and stamps drawn around it: absent ones
+    (``None``, packed as ``NO_STAMP``), its own vector from its own and
+    from another gatekeeper, one component up or down, one up and
+    another down (concurrent), a lower epoch with a larger vector, a
+    higher epoch with a smaller one, and random draws; shuffled."""
+    def mk(epoch, c, gk):
+        c = tuple(int(x) for x in c)
+        return Stamp(epoch, c, gk, c[gk])
+
+    qc = rng.integers(1, 5, g)
+    q = mk(1, qc, 0)
+    stamps = [None] * 3 + [mk(1, qc, 0), mk(1, qc, g - 1)]
+    for i in range(g):
+        up, down = qc.copy(), qc.copy()
+        up[i] += 1
+        down[i] -= 1
+        stamps += [mk(1, up, i), mk(1, down, i)]
+        if g > 1:
+            j = (i + 1) % g
+            both = up.copy()
+            both[j] -= 1
+            stamps.append(mk(1, both, j))
+    stamps += [mk(0, qc + 3, 0), mk(2, qc * 0, g - 1)]
+    for _ in range(120):
+        if rng.random() < 0.1:
+            stamps.append(None)
+        else:
+            stamps.append(mk(int(rng.choice([0, 1, 1, 1, 2])),
+                             rng.integers(0, 6, g), int(rng.integers(g))))
+    return q, [stamps[i] for i in rng.permutation(len(stamps))]
+
+
+FORMS = ["np_before", "jnp_before", "pairs_np", "pairs_jnp", "concurrent",
+         "before_mask"]
+
+
+class TestStampOrderCopies:
+    """Every copy of ``row ≺ q`` over packed rows, and the packed
+    concurrency test, against the kernel reference ``before_cm`` (on the
+    transposed rows) and the scalar :func:`compare` on the stamps."""
+
+    @pytest.mark.parametrize("form", FORMS)
+    @pytest.mark.parametrize("g", [1, 2, 4, 8])
+    def test_form_agrees_with_reference_and_compare(self, g, form):
+        rng = np.random.default_rng(100 * g + FORMS.index(form))
+        cases = [_rows_around(rng, g) for _ in range(3)]
+        for q, stamps in cases:
+            rel = [None if s is None else compare(s, q) for s in stamps]
+            assert None in rel
+            assert {Order.BEFORE, Order.AFTER, Order.EQUAL} <= set(rel)
+            assert {s.epoch for s in stamps if s} == {0, 1, 2}
+            if g > 1:
+                assert Order.CONCURRENT in rel
+        packed = [(pack(q, g), pack_many(stamps, g)) for q, stamps in cases]
+        # the scalar answers; packed rows carry no gatekeeper id, so an
+        # equal vector is "possibly concurrent" there
+        want_before = [np.array([s is not None
+                                 and compare(s, q) is Order.BEFORE
+                                 for s in stamps])
+                       for q, stamps in cases]
+        want_conc = [np.array([s is not None and compare(s, q) in
+                               (Order.EQUAL, Order.CONCURRENT)
+                               for s in stamps])
+                     for q, stamps in cases]
+        ref_before = [np.asarray(before_cm(jnp.asarray(rows.T),
+                                           jnp.asarray(qr)))
+                      for qr, rows in packed]
+        for rb, wb in zip(ref_before, want_before):
+            np.testing.assert_array_equal(rb, wb)
+
+        if form in ("pairs_np", "pairs_jnp"):
+            # one call over every case's rows, each row with its own query
+            rows = np.concatenate([r for _, r in packed])
+            qs = np.concatenate([np.tile(qr, (r.shape[0], 1))
+                                 for qr, r in packed])
+            xp = np if form == "pairs_np" else jnp
+            got = np.asarray(writepath._before_pairs_xp(
+                xp, xp.asarray(rows), xp.asarray(qs)))
+            np.testing.assert_array_equal(got, np.concatenate(ref_before))
+            np.testing.assert_array_equal(got, np.concatenate(want_before))
+            return
+        for (qr, rows), rb, wb, wc in zip(packed, ref_before, want_before,
+                                          want_conc):
+            if form == "concurrent":
+                got = clock.concurrent_mask_np(rows, qr)
+                np.testing.assert_array_equal(
+                    np.asarray(clock.concurrent_mask(jnp.asarray(rows),
+                                                     jnp.asarray(qr))), got)
+                # same epoch, present, and neither row ≺ q nor q ≺ row
+                q_before_row = np.asarray(jax.vmap(
+                    before_cm, in_axes=(None, 0))(jnp.asarray(qr[:, None]),
+                                                  jnp.asarray(rows)))[:, 0]
+                same = (rows[:, 0] == qr[0]) & (rows[:, 0] != clock.NO_STAMP)
+                np.testing.assert_array_equal(got,
+                                              same & ~rb & ~q_before_row)
+                np.testing.assert_array_equal(got, wc)
+                continue
+            if form == "np_before":
+                got = clock._np_before(rows, qr)
+            elif form == "jnp_before":
+                got = np.asarray(clock._jnp_before(jnp.asarray(rows),
+                                                   jnp.asarray(qr)))
+            else:                       # the kernel body, interpreted
+                got = np.asarray(mv_ops.before_mask(
+                    jnp.asarray(rows), jnp.asarray(qr), interpret=True))
+            np.testing.assert_array_equal(got, rb)
+            np.testing.assert_array_equal(got, wb)
 
 
 class TestOracle:

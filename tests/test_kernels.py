@@ -286,93 +286,3 @@ class TestSegmentSum:
         with pytest.raises(ValueError, match="non-decreasing"):
             ops.tile_layout(np.array([3, 1, 2], np.int32), 4)
 
-
-# ---------------------------------------------------------- flash_attention
-class TestFlashAttention:
-    @pytest.mark.parametrize("bh,sq,sk,d,causal,window,dtype", [
-        (2, 128, 128, 64, True, None, jnp.float32),
-        (1, 256, 256, 32, True, None, jnp.float32),
-        (3, 128, 128, 64, False, None, jnp.float32),
-        (2, 128, 128, 64, True, 64, jnp.float32),     # sliding window
-        (2, 128, 256, 64, True, None, jnp.float32),   # decode-ish sk>sq
-        (2, 256, 256, 128, True, None, jnp.bfloat16),
-    ])
-    def test_matches_ref(self, bh, sq, sk, d, causal, window, dtype):
-        from repro.kernels.flash_attention.kernel import \
-            flash_attention_pallas
-        from repro.kernels.flash_attention.ref import attention_ref
-        rng = np.random.default_rng(sq + sk + d)
-        q = jnp.asarray(rng.normal(size=(bh, sq, d)), dtype)
-        k = jnp.asarray(rng.normal(size=(bh, sk, d)), dtype)
-        v = jnp.asarray(rng.normal(size=(bh, sk, d)), dtype)
-        got = flash_attention_pallas(q, k, v, causal=causal, window=window,
-                                     block_q=64, block_k=64)
-        ref = attention_ref(q, k, v, causal=causal, window=window)
-        tol = 3e-2 if dtype == jnp.bfloat16 else 2e-5
-        np.testing.assert_allclose(np.asarray(got, np.float32),
-                                   np.asarray(ref, np.float32),
-                                   rtol=tol, atol=tol)
-
-    def test_gqa_wrapper_matches_model_attention(self):
-        from repro.kernels.flash_attention import ops
-        from repro.models.layers import attention as model_attention
-        rng = np.random.default_rng(5)
-        b, s, hq, hkv, d = 2, 128, 8, 2, 32
-        q = jnp.asarray(rng.normal(size=(b, s, hq, d)), jnp.float32)
-        k = jnp.asarray(rng.normal(size=(b, s, hkv, d)), jnp.float32)
-        v = jnp.asarray(rng.normal(size=(b, s, hkv, d)), jnp.float32)
-        pos = jnp.broadcast_to(jnp.arange(s), (b, s))
-        got = ops.mha(q, k, v, causal=True)
-        ref = model_attention(q, k, v, pos, pos, causal=True)
-        np.testing.assert_allclose(np.asarray(got), np.asarray(ref),
-                                   rtol=2e-5, atol=2e-5)
-
-    @given(st.sampled_from([64, 128]), st.sampled_from([64, 128]),
-           st.sampled_from([32, 64]), st.integers(0, 10**6))
-    @settings(max_examples=10, deadline=None)
-    def test_property_rowsums(self, sq, sk, d, seed):
-        """Attention output of constant-V must be ~V (probs sum to 1)."""
-        from repro.kernels.flash_attention.kernel import \
-            flash_attention_pallas
-        rng = np.random.default_rng(seed)
-        q = jnp.asarray(rng.normal(size=(1, sq, d)), jnp.float32)
-        k = jnp.asarray(rng.normal(size=(1, sk, d)), jnp.float32)
-        v = jnp.ones((1, sk, d), jnp.float32) * 3.5
-        got = flash_attention_pallas(q, k, v, causal=False,
-                                     block_q=64, block_k=64)
-        np.testing.assert_allclose(np.asarray(got), 3.5, rtol=1e-5)
-
-
-# ------------------------------------------------------------ embedding_bag
-class TestEmbeddingBag:
-    @pytest.mark.parametrize("v,d,b,l,weighted,mode,dtype", [
-        (50, 128, 4, 6, False, "sum", jnp.float32),
-        (200, 128, 8, 10, True, "sum", jnp.float32),
-        (100, 256, 3, 5, True, "mean", jnp.float32),
-        (64, 128, 16, 4, False, "mean", jnp.float32),
-        (32, 128, 5, 7, True, "sum", jnp.bfloat16),
-    ])
-    def test_matches_ref(self, v, d, b, l, weighted, mode, dtype):
-        from repro.kernels.embedding_bag import ops
-        rng = np.random.default_rng(v + b)
-        table = jnp.asarray(rng.normal(size=(v, d)), dtype)
-        idx = rng.integers(0, v, size=(b, l)).astype(np.int32)
-        idx[rng.random((b, l)) < 0.2] = -1             # padding
-        # positive weights: mean-mode normalizes by sum(w), which must
-        # stay away from 0 for a well-conditioned comparison
-        w = jnp.asarray(np.abs(rng.normal(size=(b, l))) + 0.1,
-                        jnp.float32) if weighted else None
-        got = ops.embedding_bag(table, jnp.asarray(idx), w, mode=mode)
-        ref = ops.embedding_bag(table, jnp.asarray(idx), w, mode=mode,
-                                use_ref=True)
-        tol = 3e-2 if dtype == jnp.bfloat16 else 1e-5
-        np.testing.assert_allclose(np.asarray(got, np.float32),
-                                   np.asarray(ref, np.float32),
-                                   rtol=tol, atol=tol)
-
-    def test_all_padding_bag_is_zero(self):
-        from repro.kernels.embedding_bag import ops
-        table = jnp.ones((10, 128), jnp.float32)
-        idx = jnp.full((2, 3), -1, jnp.int32)
-        got = ops.embedding_bag(table, idx)
-        np.testing.assert_array_equal(np.asarray(got), 0.0)

@@ -476,3 +476,122 @@ class TestOpenLoopWatchdog:
                                     ("get_node", [(f"u{i % 4}", None)])),
             timeout=10.0)
         assert out["completed"] == 30 and out["ok"] == 30
+
+
+# ---------------------------------------------------------------------------
+# GraphQueryServer: the serving driver's own contract
+# ---------------------------------------------------------------------------
+
+class TestGraphQueryServer:
+    @staticmethod
+    def _server(n=4, **kw):
+        from repro.runtime.server import GraphQueryServer
+        w = make_weaver(**kw)
+        seed_vertices(w, n)
+        return GraphQueryServer(w)
+
+    @pytest.mark.parametrize("kind", ["tx", "prog"])
+    def test_submit_records_one_completion(self, kind):
+        """One completion of its kind, the caller's callback once with
+        the same arguments, and ``inflight`` back to 0."""
+        srv = self._server(seed=1)
+        w = srv.weaver
+        if kind == "tx":
+            payload = w.begin_tx()
+            payload.create_vertex("n0")
+        else:
+            payload = ("get_node", [("u0", None)])
+        calls = []
+        srv.submit(kind, payload, on_done=lambda *a: calls.append(a))
+        assert srv.inflight == 1
+        srv.drain(timeout=1.0)
+        w.settle(50e-3)
+        assert srv.inflight == 0
+        assert [r["kind"] for r in srv.completed] == [kind]
+        assert calls == [srv.completed[0]["result"]]
+        if kind == "tx":
+            (res,) = calls[0]
+            assert res.ok and w.read_vertex("n0") is not None
+        else:
+            result, stamp, latency = calls[0]
+            assert result is not None and stamp is not None
+            assert latency > 0
+
+    def test_drain_stops_at_timeout_with_requests_outstanding(self):
+        """Reads shed with no session and no nack never complete: drain
+        returns at its timeout, counting each request once."""
+        srv = self._server(seed=3, n_gk=1, n_shards=1,
+                           admission_queue_limit=1, read_retry_timeout=0.0,
+                           shed_nack=False)
+        sim = srv.weaver.sim
+        for i in range(40):
+            srv.submit("prog", ("get_node", [(f"u{i % 4}", None)]))
+        t0 = sim.now
+        srv.drain(timeout=50e-3)
+        assert sim.now == pytest.approx(t0 + 50e-3)
+        assert srv.inflight > 0
+        assert srv.inflight + len(srv.completed) == 40
+
+    @pytest.mark.parametrize("n_clients,n_requests", [(1, 8), (4, 30),
+                                                      (16, 5)])
+    def test_closed_loop_completes_n_requests_within_n_clients(
+            self, n_clients, n_requests):
+        """Exactly ``n_requests`` issued, in order, and completed, with
+        never more than ``n_clients`` in flight (and that many while the
+        backlog lasts); every write is applied."""
+        srv = self._server(seed=2)
+        w = srv.weaver
+        live = {"now": 0, "max": 0}
+        submit_tx, submit_program = w.submit_tx, w.submit_program
+
+        def start(cb):
+            live["now"] += 1
+            live["max"] = max(live["max"], live["now"])
+
+            def done(*a):
+                live["now"] -= 1
+                cb(*a)
+            return done
+
+        w.submit_tx = lambda tx, cb, **kw: submit_tx(tx, start(cb), **kw)
+        w.submit_program = lambda name, entries, cb, **kw: submit_program(
+            name, entries, start(cb), **kw)
+        asked = []
+
+        def make_request(i):
+            asked.append(i)
+            if i % 3 == 0:
+                tx = w.begin_tx()
+                tx.create_vertex(f"c{i}")
+                return "tx", tx
+            return "prog", ("get_node", [(f"u{i % 4}", None)])
+
+        out = srv.run_closed_loop(n_clients, n_requests, make_request,
+                                  timeout=5.0)
+        assert asked == list(range(n_requests))
+        assert (out["issued"], out["completed"], out["ok"]) == \
+            (n_requests, n_requests, n_requests)
+        assert len(out["latencies_s"]) == n_requests
+        assert live["now"] == 0
+        assert live["max"] == min(n_clients, n_requests)
+        assert all(w.read_vertex(f"c{i}") is not None
+                   for i in range(0, n_requests, 3))
+
+    def test_open_loop_latency_per_success_and_failures_counted(self):
+        """Past the retry budget reads complete as failures: one latency
+        per request that succeeded, and the rest counted as the sessions
+        that gave up."""
+        srv = self._server(n=2, seed=13, n_gk=1, admission_queue_limit=1,
+                           read_retry_timeout=0.5e-3,
+                           client_retry_budget=2)
+        gaveup0 = srv.weaver.counters()["prog_gaveup"]
+        out = srv.run_open_loop(
+            rate=1e6, n_requests=64,
+            make_request=lambda i: ("prog", ("get_node", [("u0", None)])),
+            timeout=1.0)
+        failed = out["completed"] - out["ok"]
+        assert out["issued"] == out["completed"] == 64
+        assert 0 < failed < 64
+        assert len(out["latencies_s"]) == out["ok"]
+        assert all(lat > 0 for lat in out["latencies_s"])
+        assert srv.weaver.counters()["prog_gaveup"] - gaveup0 == failed

@@ -1,16 +1,14 @@
-"""Tests for optimizer, checkpointing (refinable-timestamp MVCC),
-trainer fault tolerance, gradient compression, and the dynamic-graph
-pipeline."""
+"""Tests for optimizer, checkpointing (refinable-timestamp MVCC), and the
+dynamic-graph pipeline."""
 import os
 
-import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
 
 from repro.checkpoint import MVCheckpointStore
 from repro.core.clock import Order, Stamp, compare
-from repro.optim import AdamWConfig, adamw, compress, make_train_step
+from repro.optim import AdamWConfig, adamw, make_train_step
 
 
 def quad_loss(params, batch):
@@ -53,24 +51,6 @@ class TestAdamW:
         g = {"w": jnp.full((10,), 100.0)}
         clipped, norm = adamw.clip_by_global_norm(g, 1.0)
         assert float(adamw.global_norm(clipped)) <= 1.0 + 1e-5
-
-
-class TestGradCompression:
-    def test_int8_roundtrip_with_error_feedback(self):
-        rng = np.random.default_rng(0)
-        g = {"a": jnp.asarray(rng.normal(size=(256,)), jnp.float32)}
-        ef = compress.init_error_feedback(g)
-        total_sent = jax.tree_util.tree_map(jnp.zeros_like, g)
-        total_true = jax.tree_util.tree_map(jnp.zeros_like, g)
-        for _ in range(20):
-            q, ef = compress.compress_grads(g, ef)
-            deq = compress.decompress_grads(q)
-            total_sent = jax.tree_util.tree_map(jnp.add, total_sent, deq)
-            total_true = jax.tree_util.tree_map(jnp.add, total_true, g)
-        # error feedback: accumulated quantized sum tracks the true sum
-        np.testing.assert_allclose(np.asarray(total_sent["a"]),
-                                   np.asarray(total_true["a"]),
-                                   rtol=0.02, atol=0.05)
 
 
 class TestMVCheckpoint:
@@ -126,67 +106,6 @@ class TestMVCheckpoint:
         store.save({"w": jnp.zeros((2,))}, step=1)
         os.makedirs(tmp_path / "v_e0_99", exist_ok=True)  # no MANIFEST
         assert store.latest().step == 1
-
-
-class TestTrainerFaultTolerance:
-    def test_checkpoint_restart_resumes(self, tmp_path):
-        from repro.runtime import Trainer, TrainerConfig
-        rng = np.random.default_rng(0)
-        params = {"w": jnp.asarray(rng.normal(size=(4, 4)), jnp.float32)}
-        batch = make_batch(rng)
-        def batches():
-            while True:
-                yield batch
-        cfg = TrainerConfig(total_steps=30, ckpt_every=10,
-                            ckpt_dir=str(tmp_path), log_every=1000)
-        t1 = Trainer(quad_loss, params,
-                     AdamWConfig(lr=1e-2, warmup_steps=1, total_steps=30),
-                     cfg)
-        t1.fit(batches(), until=20)
-        assert t1.step == 20
-        # simulated crash: brand-new trainer resumes from stamp
-        t2 = Trainer(quad_loss, params,
-                     AdamWConfig(lr=1e-2, warmup_steps=1, total_steps=30),
-                     cfg)
-        assert t2.try_resume()
-        assert t2.step == 20
-        np.testing.assert_allclose(np.asarray(t2.params["w"]),
-                                   np.asarray(t1.params["w"]))
-        t2.fit(batches())
-        assert t2.step == 30
-
-    def test_failure_bumps_epoch(self, tmp_path):
-        from repro.runtime import Trainer, TrainerConfig
-        rng = np.random.default_rng(0)
-        params = {"w": jnp.asarray(rng.normal(size=(4, 4)), jnp.float32)}
-        batch = make_batch(rng)
-        def batches():
-            while True:
-                yield batch
-        cfg = TrainerConfig(total_steps=20, ckpt_every=5,
-                            ckpt_dir=str(tmp_path), log_every=1000)
-        t = Trainer(quad_loss, params,
-                    AdamWConfig(lr=1e-2, warmup_steps=1, total_steps=20),
-                    cfg)
-        t.fit(batches(), until=10)
-        t.on_failure()
-        assert t.store.epoch == 1
-        assert t.step == 10
-        t.fit(batches())
-        last = t.store.latest()
-        assert last.stamp.epoch == 1
-
-    def test_straggler_detection(self):
-        from repro.runtime import HeartbeatMonitor
-        m = HeartbeatMonitor(n_workers=3, factor=3.0)
-        now = 0.0
-        for step in range(8):
-            for w in range(3):
-                if not (w == 2 and step >= 4):
-                    m.beat(w, now + 0.01 * w)
-            now += 1.0
-        flagged = m.check(now)
-        assert 2 in flagged
 
 
 class TestDynamicGraphPipeline:
